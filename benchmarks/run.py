@@ -52,6 +52,9 @@ def main() -> None:
     ap.add_argument("--trace", default=None, metavar="OUT",
                     help="record obs events and write a Chrome trace to OUT")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.trace:
         from repro.obs import trace as obs_trace
         obs_trace.enable(capacity=1 << 20)

@@ -150,9 +150,7 @@ def _overlap_sweep(transports, validate_sim):
 
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
-
-        f = jax.jit(shard_map(
+        f = jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=P(("gx", "gy")),
             out_specs=P(("gx", "gy")),
         ))

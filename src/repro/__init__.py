@@ -1,19 +1,7 @@
 """repro: SMI (Streaming Message Interface) rendered for JAX TPU meshes.
 
-Importing the package installs the JAX version-compat shims (see
-:mod:`repro.compat`) so the modern API surface (``jax.shard_map`` et al.)
-is available on every supported runtime before any submodule uses it.
-
-When jax is absent the install is skipped instead of failing the import:
-the stdlib-only analysis layer (``repro.analysis`` — the smilint AST
-rules and ledger verifier, DESIGN.md §14) must stay importable in
-jax-free environments (the CI lint job); everything that actually uses
-jax still fails at ITS import, with the real ImportError.
+The package root imports nothing: the stdlib-only analysis layer
+(``repro.analysis`` — the smilint AST rules and ledger verifier,
+DESIGN.md §14) must stay importable in jax-free environments (the CI lint
+job).
 """
-
-import importlib.util as _ilu
-
-if _ilu.find_spec("jax") is not None:
-    from . import compat as _compat
-
-    _compat.install()

@@ -112,7 +112,6 @@ def capture_bench_collectives(size: int = 8):
         open_reduce_channel,
         open_scatter_channel,
     )
-    from ..compat import shard_map
     from ..core import Communicator, make_test_mesh
 
     mesh = make_test_mesh((size,), ("x",))
@@ -128,7 +127,7 @@ def capture_bench_collectives(size: int = 8):
         a = open_allreduce_channel(comm, port=None).transfer(v[0])
         return b[None], r[None], gt[None], s[None], a[None]
 
-    f = shard_map(body, mesh=mesh, in_specs=(P("x"), P("x"), P(None)),
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("x"), P("x"), P(None)),
                   out_specs=(P("x"),) * 5)
     with _capture.capture() as led:
         jax.jit(f).lower(
@@ -147,7 +146,6 @@ def capture_quickstart(size: int = 8, count: int = 12):
     from jax.sharding import PartitionSpec as P
 
     from ..channels import open_bcast_channel, open_channel
-    from ..compat import shard_map
     from ..core import Communicator, Topology, make_test_mesh, pvary
 
     mesh = make_test_mesh((size,), ("x",))
@@ -179,7 +177,7 @@ def capture_quickstart(size: int = 8, count: int = 12):
                                n_chunks=2).transfer(y)
         return y[None] + 0 * dummy[:, :1]
 
-    f = shard_map(spmd, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    f = jax.shard_map(spmd, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     with _capture.capture() as led:
         jax.jit(f).lower(jax.ShapeDtypeStruct((size, 1), jnp.float32))
     return led

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map as _shard_map
 from ..core.collectives import _schedule_loop
 from ..core.comm import Communicator
 from ..core.streaming import make_test_mesh
@@ -170,7 +169,7 @@ class DistributedStencil:
             )[None]
 
         return jax.jit(
-            _shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec)
+            jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec)
         )
 
     # -- host-side domain plumbing ----------------------------------------

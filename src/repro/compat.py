@@ -1,143 +1,31 @@
-"""JAX version-compat layer.
+"""Mesh and varying-manual-axes helpers over JAX's explicit-sharding API.
 
-The codebase is written against the modern explicit-sharding JAX API
-(``jax.shard_map``, ``jax.sharding.AxisType``, ``lax.pcast`` / the
-varying-manual-axes type system).  Older runtimes (jax < 0.6) expose the
-same functionality under different names — or not at all, in which case the
-feature is a semantic no-op (pre-VMA shard_map never tracked varyingness).
-
-This module gives every call site one stable surface:
-
-* :func:`shard_map` — ``jax.shard_map`` when present, else the
-  ``jax.experimental.shard_map`` fallback.  The modern ``check_vma=``
-  kwarg is translated to the legacy ``check_rep=`` (both are pure
-  validation toggles; replication checking on legacy jax rejects valid
-  masked-ppermute programs, so the fallback disables it).
-* :func:`make_mesh` — ``jax.make_mesh`` with ``axis_types=Auto`` when the
-  runtime knows about axis types, plain ``jax.make_mesh`` otherwise.
-* :func:`pvary_missing` / :func:`vma_of` — ``lax.pcast``-based VMA casts on
-  runtimes with the VMA type system, identity elsewhere.
-
-Importing :mod:`repro` installs :func:`shard_map` as ``jax.shard_map`` when
-the attribute is missing, so tests/benchmarks/examples written against the
-modern spelling run unchanged on legacy runtimes.
+* :func:`make_mesh` — ``jax.make_mesh`` with every axis ``AxisType.Auto``.
+* :func:`vma_of` / :func:`pvary_missing` — read and widen an array's
+  varying-manual-axes set (``jax.typeof(x).vma``, ``lax.pcast``), which
+  ``shard_map(check_vma=True)`` requires of loop carries and kernel outputs.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 from jax import lax
-
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_VMA = hasattr(lax, "pcast") and hasattr(jax, "typeof")
-
-try:
-    from jax.sharding import AxisType  # jax >= 0.6
-
-    HAS_AXIS_TYPES = True
-except ImportError:  # legacy: meshes have no axis types
-    AxisType = None
-    HAS_AXIS_TYPES = False
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True, **kw):
-    """Version-stable ``jax.shard_map``.
-
-    Accepts the modern keyword surface; on legacy runtimes dispatches to
-    ``jax.experimental.shard_map.shard_map`` with replication checking off
-    (the legacy checker predates masked collectives and rejects valid SMI
-    schedules — it is validation only, never semantics).
-    """
-    if HAS_NATIVE_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma, **kw,
-        )
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    return _legacy(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, **kw,
-    )
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, names):
-    """``jax.make_mesh`` with Auto axis types when the runtime has them."""
+    """``jax.make_mesh`` with Auto axis types."""
     shape, names = tuple(shape), tuple(names)
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, names)
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def vma_of(x) -> frozenset:
-    """Varying-manual-axes of ``x`` (empty set on pre-VMA runtimes)."""
-    if not HAS_VMA:
-        return frozenset()
+    """Varying-manual-axes of ``x`` (empty outside ``shard_map``)."""
     return getattr(jax.typeof(x), "vma", frozenset())
 
 
 def pvary_missing(v, names):
     """Cast ``v`` varying over every axis in ``names`` it is not already
-    varying over.  Identity on runtimes without the VMA type system (there,
-    constants created inside shard_map are implicitly device-varying)."""
-    if not HAS_VMA:
-        return v
+    varying over."""
     missing = tuple(n for n in names if n not in vma_of(v))
     return lax.pcast(v, missing, to="varying") if missing else v
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _psum_rep(x, axis_name):
-    return lax.psum(x, axis_name)
-
-
-def _psum_rep_fwd(x, axis_name):
-    return lax.psum(x, axis_name), None
-
-
-def _psum_rep_bwd(_axis_name, _res, g):
-    return (g,)
-
-
-_psum_rep.defvjp(_psum_rep_fwd, _psum_rep_bwd)
-
-
-def psum_replicated(x, axis_name):
-    """``lax.psum`` whose result is *replicated* over ``axis_name`` and whose
-    AD transpose is therefore the identity.
-
-    Modern jax derives this from the VMA type system.  Legacy shard_map with
-    replication checking off transposes psum back to psum, over-counting
-    replicated cotangents by the axis size; the custom_vjp restores the
-    correct identity transpose there.
-    """
-    if HAS_VMA:
-        return lax.psum(x, axis_name)
-    return _psum_rep(x, axis_name)
-
-
-def tpu_compiler_params(**kw):
-    """``pltpu.CompilerParams`` across its rename (legacy: TPUCompilerParams)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
-
-
-def install():
-    """Install shims onto the ``jax`` namespace (idempotent).
-
-    Only fills gaps — never overrides a native attribute — so running on a
-    modern jax leaves the runtime untouched.
-    """
-    if not HAS_NATIVE_SHARD_MAP:
-
-        @functools.wraps(shard_map)
-        def _jax_shard_map(f, mesh, in_specs, out_specs, **kw):
-            return shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-            )
-
-        jax.shard_map = _jax_shard_map

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import psum_replicated
 from .comm import Communicator
 from .streaming import _mask_sel, _pvary
 
@@ -107,4 +106,4 @@ def pipeline_loss(
     M = x_mb.shape[0]
     per_mb = jax.vmap(loss_fn)(out, y_mb)  # (M,)
     local = jnp.where(r == P - 1, per_mb.mean(), 0.0)
-    return psum_replicated(local, comm.axis)
+    return lax.psum(local, comm.axis)

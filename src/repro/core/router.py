@@ -39,10 +39,12 @@ Three implementations of the identical tick semantics (DESIGN.md §10):
   batched tick loop (a scan of cond'd batches — reverse-differentiable)
   that goes idle as soon as the network drains;
 * ``impl="pallas"`` — the vector tick as a Pallas kernel
-  (``kernels/router``) whose FIFO/arbiter state is aliased in place
-  (VMEM-resident on TPU); interpret-mode fallback elsewhere.
+  (``kernels/router``) whose FIFO/arbiter state is aliased in place:
+  compiled by Mosaic on TPU, run by the Pallas interpreter elsewhere.  It
+  is forward-only (a ``pallas_call`` has no transpose).
 
-``impl=None`` auto-selects: pallas on TPU, vector otherwise.  All three
+``impl=None`` is "vector" on every platform: the training path
+differentiates through the router, which the kernel cannot.  All three
 produce bit-identical ``(out_pay, out_cnt, overflow, t_done)`` — asserted
 by the equivalence tests in ``tests/test_router.py``.
 """
@@ -57,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..compat import pvary_missing, vma_of
 from ..obs import trace as obs
 from .comm import Communicator
 from .routing import compute_route_table, physical_link_map
@@ -164,18 +167,10 @@ class RouterConfig:
     # cost: switching input FIFOs costs one dead cycle on the link (the
     # paper's Tab. 4 effect; our combinational arbiter has no such cost
     # physically, so it is opt-in for the reproduction benchmark)
-    tick_batch: int | None = None  # ticks advanced per loop body in the
-    # vector/pallas datapath; the drain check runs once per batch, so
-    # up to tick_batch - 1 idle (identity) ticks run past the drain point.
-    # None = adaptive: 2 on the packed exchange (its drain check is free —
-    # the pending count rides in the packet's lane), 4 on the psum
-    # fallback, where deeper batches amortize the extra collective
-
-
-def _default_impl() -> str:
-    from ..kernels.common import on_tpu
-
-    return "pallas" if on_tpu() else "vector"
+    tick_batch: int = 4  # ticks advanced per loop body in the
+    # vector/pallas datapath; the drain check (one psum of the pending
+    # count) runs once per batch, so up to tick_batch - 1 idle (identity)
+    # ticks run past the drain point
 
 
 def _exchange_tables(links, n: int):
@@ -208,21 +203,19 @@ def run_router(
     n_steps: int,
     *,
     impl: str | None = None,
-    interpret: bool | None = None,
 ):
     """Execute up to ``n_steps`` router cycles.  Must run inside shard_map.
 
     Returns (out_pay, out_cnt, overflow, t_done): per-port delivery
     buffers, their fill counts, the loss counter (0 == lossless run) and
     the last delivery tick.  ``impl`` picks the datapath ("scalar" |
-    "vector" | "pallas"; None auto-selects — see module docstring); the
+    "vector" | "pallas"; None is "vector" — see module docstring); the
     vector/pallas datapaths may stop early once the network drains, which
-    never changes the returned values.  ``interpret`` forces the Pallas
-    tick kernel through the interpreter (None: interpret off TPU).
+    never changes the returned values.
     """
     links = make_links(cfg.dims)
     if impl is None:
-        impl = _default_impl()
+        impl = "vector"
     if impl != "scalar" and (not links or inq_pay.dtype != jnp.float32):
         # degenerate fabrics (no links) and exotic wire dtypes keep the
         # reference path; the packetised wire is always f32
@@ -237,7 +230,7 @@ def run_router(
     assert impl in ("vector", "pallas"), impl
     return _run_router_vector(
         cfg, comm, route_tbl, inq_pay, inq_dst, inq_len, n_steps, links,
-        use_pallas=impl == "pallas", interpret=interpret)
+        use_pallas=impl == "pallas")
 
 
 def _run_router_scalar(
@@ -392,21 +385,20 @@ def _run_router_scalar(
 
 def _run_router_vector(
     cfg, comm, route_tbl, inq_pay, inq_dst, inq_len, n_steps, links, *,
-    use_pallas: bool, interpret: bool | None,
+    use_pallas: bool,
 ):
     """Vectorized batched-tick datapath (DESIGN.md §10).
 
     Per tick: ``router_tick`` (absorb + one-shot arbitration, pure array
     ops — or the Pallas kernel wrapping the same function) followed by ONE
-    packed ``all_to_all`` moving every link's packet row plus a global
-    pending lane.  The tick loop is a ``scan`` of ``cond``'d batches
-    advancing ``cfg.tick_batch`` ticks each that go idle as soon as the
-    pending lane reports the network drained — idle ticks are identity
+    packed ``all_to_all`` moving every link's packet row.  The tick loop
+    is a ``scan`` of ``cond``'d batches advancing ``cfg.tick_batch`` ticks
+    each that go idle as soon as the psum'd pending count reports the
+    network drained — idle ticks are identity
     on every returned value, so the early out is output-invariant with
     the scalar reference running all ``n_steps`` cycles, and scan+cond
     keep the datapath reverse-differentiable for the training path.
     """
-    from ..compat import HAS_VMA
     from ..kernels.common import on_tpu
     from ..kernels.router import router_absorb, router_tick, \
         router_tick_pallas, tick_spec_of
@@ -415,22 +407,24 @@ def _run_router_vector(
     r = comm.rank()
     E = cfg.pkt_elems
     NL = len(links)
-    F = E + 4  # lanes: dst, port, valid, pending + payload
+    F = E + 3  # lanes: dst, port, valid + payload
     spec = tick_spec_of(cfg, n, [lid for lid, _ in links])
     my_tbl = route_tbl[jnp.minimum(r, n - 1)]
     inq_len = inq_len.astype(jnp.int32)
     nbr, src, packed_ok = _exchange_tables(links, n)
     nbr_r = jnp.asarray(nbr)[jnp.minimum(r, n - 1)]
     src_r = jnp.asarray(src)[jnp.minimum(r, n - 1)]
-    if interpret is None:
-        interpret = not on_tpu()
-    # the drain predicate must be replicated: on VMA runtimes that is a
-    # psum of the local pending count; pre-VMA runtimes read the packed
-    # exchange's own pending lane (same value, no extra collective)
-    lane_live = packed_ok and not HAS_VMA
+
+    # the state varies over the comm axes and over every axis an operand
+    # varies over (a router on "model" inside a (data, model) mesh); the
+    # drain count is psum'd over the comm axes only
+    vma = frozenset(comm.axis_names).union(
+        *(vma_of(a) for a in (route_tbl, inq_pay, inq_dst, inq_len)))
+    live0 = pvary_missing(jnp.asarray(1, jnp.int32),
+                          tuple(vma - frozenset(comm.axis_names)))
 
     def init():
-        z = lambda *sh_dt: _pvary(jnp.zeros(*sh_dt), comm)
+        z = lambda *sh_dt: pvary_missing(jnp.zeros(*sh_dt), tuple(vma))
         st = dict(
             inq_head=z((cfg.n_ports,), jnp.int32),
             tr_pay=z((cfg.transit_cap, E), inq_pay.dtype),
@@ -453,37 +447,31 @@ def _run_router_vector(
         if use_pallas:
             return router_tick_pallas(
                 spec, my_tbl, inq_pay, inq_dst, inq_len, st, *arr, r, t,
-                interpret=interpret)
+                interpret=not on_tpu())
         return router_tick(
             spec, my_tbl, inq_pay, inq_dst, inq_len, st, *arr, r, t)
 
     def exchange(snd_pay, snd_dst, snd_prt, snd_val, pending):
-        pend_f = pending.astype(jnp.float32)
         row = jnp.concatenate([
             snd_dst.astype(jnp.float32)[:, None],
             snd_prt.astype(jnp.float32)[:, None],
             snd_val.astype(jnp.float32)[:, None],
-            jnp.broadcast_to(pend_f, (NL,))[:, None],
             snd_pay,
         ], axis=1)                                           # (NL, F)
         if packed_ok:
             # one collective for the whole fabric: row li rides at the
-            # destination's index, every row carries the pending lane
+            # destination's index
             buf = _pvary(jnp.zeros((n, F), jnp.float32), comm)
-            buf = buf.at[:, 3].set(pend_f)
             buf = buf.at[nbr_r].set(row)
             got = lax.all_to_all(buf, comm.axis, 0, 0, tiled=True)
             rows = got[src_r]                                # (NL, F)
-            live = got[:, 3].sum().astype(jnp.int32)
         else:
             rows = jnp.stack([
                 lax.ppermute(row[li], comm.axis, pairs)
                 for li, (_lid, pairs) in enumerate(links)
             ])
-            live = jnp.asarray(0, jnp.int32)
-        if not lane_live:
-            live = lax.psum(pending, comm.axis)
-        arr = (rows[:, 4:], rows[:, 0].astype(jnp.int32),
+        live = lax.psum(pending, comm.axis)
+        arr = (rows[:, 3:], rows[:, 0].astype(jnp.int32),
                rows[:, 1].astype(jnp.int32), rows[:, 2] > 0.5)
         return arr, live
 
@@ -491,18 +479,15 @@ def _run_router_vector(
     # batches, and a batch straddling the n_steps bound would tick a
     # still-live network past the cycle budget the scalar reference stops
     # at (idle ticks are identity, over-budget *live* ticks are not)
-    req = cfg.tick_batch if cfg.tick_batch is not None \
-        else (2 if lane_live else 4)
-    B = max(1, min(int(req), int(n_steps)))
+    B = max(1, min(int(cfg.tick_batch), int(n_steps)))
     while n_steps % B:
         B -= 1
     if obs.TRACING:
         obs.emit("router.tick_batch", batch=int(B),
-                 n_batches=int(n_steps) // int(B), lane_live=bool(lane_live))
-        obs.emit("router.drain", mode="lane" if lane_live else "psum")
+                 n_batches=int(n_steps) // int(B))
 
     # early exit without while_loop: a scan over n_steps // B batches
-    # whose body is a cond — once the pending lane reports the network
+    # whose body is a cond — once the pending count reports the network
     # drained, the remaining batches take the identity branch (the taken
     # branch is all XLA executes, so drained batches cost ~nothing).
     # cond + scan both carry transpose rules, which keeps the packet
@@ -523,7 +508,7 @@ def _run_router_vector(
     st0, arr0 = init()
     (st, arr, t, _live), _ = lax.scan(
         body,
-        (st0, arr0, jnp.asarray(0, jnp.int32), jnp.asarray(1, jnp.int32)),
+        (st0, arr0, jnp.asarray(0, jnp.int32), live0),
         None, length=n_steps // B,
     )
     # the final exchange's arrivals are still in flight at loop exit

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 
 from ..compat import make_mesh as _compat_make_mesh
 from ..compat import pvary_missing
-from ..compat import shard_map as _compat_shard_map
 from .comm import Communicator
 
 
@@ -45,8 +44,7 @@ def _pvary(x, comm: "Communicator"):
 
     shard_map's varying-manual-axes type system requires loop carries that
     flow through ppermute to be 'varying'; zeros created inside the region
-    start out 'invariant'.  (jax >= 0.8 VMA typing; identity on pre-VMA
-    runtimes via the compat layer.)"""
+    start out 'invariant'."""
     names = tuple(comm.axis_names)
     return jax.tree.map(lambda v: pvary_missing(v, names), x)
 
@@ -175,7 +173,7 @@ __all__ = [
 def run_spmd(fn, mesh, in_specs, out_specs, *args):
     """jit(shard_map(fn)) one-liner used across tests and benchmarks."""
     return jax.jit(
-        _compat_shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+        jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     )(*args)
 
 

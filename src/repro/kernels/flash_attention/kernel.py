@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params
+from ..common import pallas_call
+
 
 NEG_INF = -1e30
 
@@ -115,7 +116,7 @@ def flash_attention_pallas(
         _fa_kernel, nk=grid[2], bq=block_q, bk=block_k,
         scale=scale, causal=causal, window=window, skv=skv,
     )
-    return pl.pallas_call(
+    return pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -130,7 +131,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

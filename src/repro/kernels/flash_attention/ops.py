@@ -32,14 +32,25 @@ def flash_attention(
     use_pallas: bool | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Multi-head attention with GQA; (B, S, H, D) layouts throughout."""
-    if not resolve_use_pallas(use_pallas) and not interpret:
-        if q.shape[1] * k.shape[1] > 2048 * 2048:
-            return attention_chunked_ref(
-                q, k, v, scale=scale, causal=causal, window=window
-            )
-        return attention_ref(q, k, v, scale=scale, causal=causal, window=window)
+    """Multi-head attention with GQA; (B, S, H, D) layouts throughout.
 
+    The Pallas kernel is forward-only; its gradient is the reference
+    attention's (recomputed in XLA from the saved q, k, v)."""
+    if not resolve_use_pallas(use_pallas) and not interpret:
+        return _reference(q, k, v, causal, window, scale)
+    return _kernel(q, k, v, causal, window, scale, block_q, block_k, interpret)
+
+
+def _reference(q, k, v, causal, window, scale):
+    if q.shape[1] * k.shape[1] > 2048 * 2048:
+        return attention_chunked_ref(
+            q, k, v, scale=scale, causal=causal, window=window
+        )
+    return attention_ref(q, k, v, scale=scale, causal=causal, window=window)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _kernel(q, k, v, causal, window, scale, block_q, block_k, interpret):
     B, Sq, H, D = q.shape
     _, Skv, Hkv, _ = k.shape
     scale = scale if scale is not None else D ** -0.5
@@ -59,3 +70,17 @@ def flash_attention(
     )
     out = out[:, :Sq].reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     return out
+
+
+def _kernel_fwd(q, k, v, causal, window, scale, block_q, block_k, interpret):
+    out = _kernel(q, k, v, causal, window, scale, block_q, block_k, interpret)
+    return out, (q, k, v)
+
+
+def _kernel_bwd(causal, window, scale, block_q, block_k, interpret, res, g):
+    _, vjp = jax.vjp(
+        lambda q, k, v: _reference(q, k, v, causal, window, scale), *res)
+    return vjp(g)
+
+
+_kernel.defvjp(_kernel_fwd, _kernel_bwd)

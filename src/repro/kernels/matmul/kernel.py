@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params
+from ..common import pallas_call
 
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
@@ -54,7 +54,7 @@ def matmul_pallas(
     out_dtype = out_dtype or x.dtype
     grid = (M // block_m, N // block_n, K // block_k)
     kernel = partial(_matmul_kernel, nk=grid[2])
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -64,7 +64,7 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

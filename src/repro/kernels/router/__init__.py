@@ -1,9 +1,9 @@
 """Vectorized router datapath kernels (DESIGN.md §10).
 
-``ref`` holds the pure single-tick datapath (absorb + arbitrate) shared by
-the lax "vector" implementation and the Pallas kernel; ``kernel`` wraps it
-in a ``pallas_call`` whose FIFO/arbiter state stays aliased in place
-(VMEM-resident on TPU) across ticks.
+``ref`` holds the pure single-tick datapath (absorb + arbitrate) of the
+lax "vector" implementation; ``kernel`` restates the same tick in ops
+Mosaic lowers, as one ``pallas_call`` whose FIFO/arbiter state stays
+aliased in place across ticks.
 """
 
 from .kernel import router_tick_pallas  # noqa: F401

@@ -1,10 +1,11 @@
 """Pure single-tick datapath of the store-and-forward router.
 
 One tick of ``core/router.py`` as whole-state array ops — no per-link
-Python loop, no per-arrival scalar scan.  Both the lax "vector"
-implementation and the Pallas kernel execute exactly this function; the
-seed's per-link scalar loop is kept in ``core/router.py`` as the reference
-the equivalence tests diff against.
+Python loop, no per-arrival scalar scan.  The lax "vector" implementation
+executes exactly this function, and the Pallas kernel
+(``kernel.py``) restates it in ops Mosaic lowers; the seed's per-link
+scalar loop is kept in ``core/router.py`` as the reference the
+equivalence tests diff against.
 
 Why one-shot arbitration is exact: the routing table maps each candidate
 source (its head packet's destination) to exactly *one* link id, so the
@@ -120,19 +121,16 @@ def router_absorb(spec: TickSpec, st, arr_pay, arr_dst, arr_prt, arr_val,
 
 
 def router_arbitrate(spec: TickSpec, my_tbl, inq_pay, inq_dst, inq_len,
-                     st, r, link_ids=None):
+                     st, r):
     """Arbitrate all links in one shot and pop the selected sources.
 
     Returns ``(st, snd_pay, snd_dst, snd_prt, snd_val, pending)`` —
     the NL outgoing link rows plus the rank's remaining-work count
     (staged + parked + in flight) for the early-exit ticker.
-    ``link_ids`` defaults to ``spec.link_ids`` as an array; the Pallas
-    kernel passes it explicitly (a closure constant can't enter a kernel).
     """
-    NP, NL, S = spec.n_ports, spec.n_links, spec.n_srcs
+    NP, S = spec.n_ports, spec.n_srcs
     n = spec.n
-    if link_ids is None:
-        link_ids = jnp.asarray(spec.link_ids, jnp.int32)
+    link_ids = jnp.asarray(spec.link_ids, jnp.int32)
 
     # candidate heads: sources 0..NP-1 = input FIFOs, S-1 = transit
     hclip = jnp.minimum(st["inq_head"], spec.fifo_cap - 1)
@@ -196,10 +194,9 @@ def router_arbitrate(spec: TickSpec, my_tbl, inq_pay, inq_dst, inq_len,
 
 
 def router_tick(spec: TickSpec, my_tbl, inq_pay, inq_dst, inq_len, st,
-                arr_pay, arr_dst, arr_prt, arr_val, r, t, link_ids=None):
+                arr_pay, arr_dst, arr_prt, arr_val, r, t):
     """One full tick: absorb the previous tick's arrivals (labelled
     ``t - 1``), then arbitrate/pop the outgoing rows for tick ``t``."""
     st = router_absorb(spec, st, arr_pay, arr_dst, arr_prt, arr_val,
                        r, t - 1)
-    return router_arbitrate(spec, my_tbl, inq_pay, inq_dst, inq_len, st, r,
-                            link_ids)
+    return router_arbitrate(spec, my_tbl, inq_pay, inq_dst, inq_len, st, r)

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import pad_to, resolve_use_pallas
-from .kernel import stencil_pallas
+from .kernel import block_rows, stencil_pallas
 from .ref import stencil_ref
 
 
@@ -16,14 +16,19 @@ from .ref import stencil_ref
 def stencil_step(
     x: jax.Array,
     *,
-    block_m: int = 128,
+    block_m: int | None = None,
     use_pallas: bool | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """One sweep of the 4-point stencil with zero (Dirichlet) boundaries."""
+    """One sweep of the 4-point stencil with zero (Dirichlet) boundaries.
+
+    ``block_m`` rows per kernel grid step (a multiple of 8); None sizes the
+    slab from the row width so it fits VMEM (:func:`block_rows`)."""
     if not resolve_use_pallas(use_pallas) and not interpret:
         return stencil_ref(x)
-    M = x.shape[0]
+    M, N = x.shape
+    if block_m is None:
+        block_m = block_rows(M, N)
     xp, _ = pad_to(x, block_m, 0)
     out = stencil_pallas(xp, block_m=block_m, interpret=interpret)
     # Zero-padded rows double as the zero Dirichlet boundary: row M-1's south

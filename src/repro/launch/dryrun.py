@@ -156,7 +156,9 @@ def main(argv=None):
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    from .cache import enable_compile_cache
 
+    enable_compile_cache()
     todo = []
     for arch, shape_name, skip in cells():
         if args.arch and arch != args.arch:

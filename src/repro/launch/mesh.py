@@ -23,5 +23,12 @@ def make_mesh(shape, axes):
     return _compat_make_mesh(shape, axes)
 
 
+def grid_for(n: int) -> tuple[int, int]:
+    """The squarest (rows, cols) grid of ``n`` devices, rows <= cols:
+    1 -> (1, 1), 4 -> (2, 2), 8 -> (2, 4)."""
+    rows = max(d for d in range(1, int(n ** 0.5) + 1) if n % d == 0)
+    return rows, n // rows
+
+
 def batch_axes_of(mesh) -> tuple[str, ...]:
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))

@@ -31,6 +31,7 @@ from ..configs import COMM_MODES, get_arch, smoke
 from ..mesh.api import ParallelCtx
 from ..models import init_lm
 from ..serving import ContinuousEngine, Request, ServeEngine
+from .cache import enable_compile_cache
 from .mesh import make_mesh
 from .steps import build_continuous_serve
 
@@ -128,6 +129,7 @@ def main(argv=None):
                          "serve.* channel ledger against netsim, byte-exact")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = smoke(cfg)

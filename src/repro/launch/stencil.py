@@ -42,7 +42,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--case", default=None, choices=sorted(STENCIL_CASES),
                     help="predefined (grid, domain, steps) cell")
-    ap.add_argument("--grid", default="2x4", help="rank grid RXxRY")
+    ap.add_argument("--grid", default=None,
+                    help="rank grid RXxRY (default: the squarest grid of "
+                         "the devices present)")
     ap.add_argument("--domain", default="256x256", help="global domain XxY")
     ap.add_argument("--steps", type=int, default=8)
     ap.add_argument("--comm-mode", default="smi",
@@ -64,8 +66,12 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from ..apps import DistributedStencil
+    from .cache import enable_compile_cache
+    from .mesh import grid_for
 
-    grid, domain, steps = _pair(args.grid), _pair(args.domain), args.steps
+    enable_compile_cache()
+    grid = _pair(args.grid) if args.grid else grid_for(jax.device_count())
+    domain, steps = _pair(args.domain), args.steps
     if args.case:
         c = STENCIL_CASES[args.case]
         grid, domain, steps = c["grid"], c["domain"], c["steps"]

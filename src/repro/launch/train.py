@@ -4,7 +4,7 @@ Wires together: arch config -> mesh -> SMI train step -> synthetic data
 pipeline -> checkpointing -> watchdog + checkpoint/restart.  CLI:
 
     PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \\
-        --steps 50 --mesh 2,4 --comm-mode smi
+        --steps 50 --comm-mode smi
 
 ``--smoke`` scales the arch to its reduced config so the driver runs on the
 host devices; the full configs are exercised via the dry-run.
@@ -25,7 +25,8 @@ from ..configs import COMM_MODES, SHAPES, get_arch, smoke
 from ..configs.base import ShapeConfig
 from ..data.pipeline import SyntheticTokenPipeline
 from ..ft import StepWatchdog
-from .mesh import make_mesh
+from .cache import enable_compile_cache
+from .mesh import grid_for, make_mesh
 from .steps import TrainSettings, build_train
 
 
@@ -128,7 +129,9 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced config (host-scale)")
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--mesh", default="2,4", help="data,model grid")
+    ap.add_argument("--mesh", default=None,
+                    help="data,model grid (default: the squarest grid of "
+                         "the devices present)")
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--comm-mode", default="smi", choices=list(COMM_MODES),
@@ -143,10 +146,12 @@ def main(argv=None):
                          "ledger against netsim's prediction, byte-exact")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = smoke(cfg)
-    dims = tuple(int(x) for x in args.mesh.split(","))
+    dims = (tuple(int(x) for x in args.mesh.split(",")) if args.mesh
+            else grid_for(jax.device_count()))
     mesh = make_mesh(dims, ("data", "model")[: len(dims)] if len(dims) == 2
                      else ("pod", "data", "model"))
     shape = ShapeConfig("cli", seq_len=args.seq_len, global_batch=args.batch,

@@ -17,7 +17,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..kernels.common import on_tpu
+from ..kernels.common import on_tpu, pallas_call
 from .registry import register_transport
 from .static import StaticTransport
 
@@ -52,7 +52,7 @@ def fused_accumulate(a: jax.Array, b: jax.Array, *, interpret: bool = False):
     # grid rows must divide evenly; fall back to one whole-array block
     if rows % block:
         block = rows
-    out = pl.pallas_call(
+    out = pallas_call(
         _accum_kernel,
         grid=(rows // block,),
         in_specs=[

@@ -92,7 +92,7 @@ class PacketTransport(Transport):
     payload; ``slack_steps`` pads the static delivery-time bound (left at
     the default it simply costs a few bubble cycles).  ``router_impl``
     picks the router datapath (``core/router.py``: "scalar" | "vector" |
-    "pallas"; None auto-selects pallas on TPU, vector elsewhere).
+    "pallas"; None is "vector").
     """
 
     pkt_elems: int = 32
@@ -254,9 +254,9 @@ class PacketTransport(Transport):
 class PallasPacketTransport(PacketTransport):
     """The packet backend pinned to the Pallas tick kernel
     (``kernels/router``): the router's FIFO/arbiter state is updated in
-    place inside one ``pallas_call`` per tick (VMEM-resident on TPU;
-    interpreter fallback elsewhere).  The bare ``"packet"`` key already
-    auto-selects this datapath on TPU — this key forces it everywhere,
-    which is how the equivalence tests drive the kernel on CPU."""
+    place inside one ``pallas_call`` per tick — a Mosaic kernel on TPU,
+    the Pallas interpreter elsewhere (the equivalence tests drive it on
+    CPU through this key).  Forward-only; the bare ``"packet"`` key runs
+    the differentiable vector datapath everywhere."""
 
     router_impl: str | None = "pallas"

@@ -33,7 +33,7 @@ from repro.channels import (
     open_allreduce_channel,
     open_channel,
 )
-from repro.core import Communicator, PortAllocator, make_test_mesh, run_spmd
+from repro.core import Communicator, PortAllocator, make_test_mesh, pvary, run_spmd
 from repro.obs import trace as obs
 from repro.transport import get_transport
 
@@ -58,7 +58,7 @@ def _pipeline_prog(comm, mesh, *, count=4, port=0):
     def fn(v):
         with open_channel(comm, count=count, src=0, dst=3, port=port,
                           elem_shape=(), dtype=jnp.float32) as ch:
-            acc = jnp.float32(0)
+            acc = pvary(jnp.float32(0), comm)  # a loop carry that pops
 
             def body(i, carry):
                 ch, acc = carry

@@ -1,6 +1,8 @@
 """Per-kernel validation: interpret=True Pallas vs pure-jnp oracles,
 sweeping shapes and dtypes (deliverable c)."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +21,7 @@ from repro.kernels import (
     stencil_step,
 )
 from repro.kernels.ssd.ops import _ssd_chunked_jnp
+from repro.kernels.stencil.kernel import block_rows
 
 RNG = np.random.RandomState
 
@@ -96,6 +99,27 @@ def test_flash_attention_local_window():
                                rtol=2e-4, atol=2e-4)
 
 
+def test_flash_attention_grad_matches_ref():
+    """The kernel is forward-only; its custom VJP is the reference's."""
+    rng = RNG(5)
+    B, S, H, Hkv, D = 1, 128, 4, 2, 32
+    q, k, v = (jnp.asarray(rng.randn(B, S, h, D) * 0.3, jnp.float32)
+               for h in (H, Hkv, Hkv))
+
+    def loss(attn, q, k, v):
+        return jnp.sum(attn(q, k, v) ** 2)
+
+    kern = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                           interpret=True, block_q=64,
+                                           block_k=64)
+    ref = lambda q, k, v: attention_ref(q, k, v, causal=True)
+    got = jax.grad(partial(loss, kern), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(partial(loss, ref), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)
+
+
 def test_flash_attention_bf16():
     rng = RNG(3)
     B, S, H, D = 1, 128, 2, 64
@@ -122,6 +146,16 @@ def test_stencil_vs_ref(M, N):
     want = stencil_ref(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("M,N", [(200, 1024), (64, 8192)])
+def test_stencil_block_from_width_vs_ref(M, N):
+    """Default block: sized from the row width, so the grid has several
+    row blocks whose 8-row halo tiles must meet exactly."""
+    x = jnp.asarray(RNG(6).randn(M, N), jnp.float32)
+    assert block_rows(M, N) < M
+    np.testing.assert_array_equal(np.asarray(stencil_step(x, interpret=True)),
+                                  np.asarray(stencil_ref(x)))
 
 
 @settings(max_examples=8, deadline=None)
