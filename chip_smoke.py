@@ -29,6 +29,8 @@ SERVE_LAYERS = 12  # 32 layers of f32 masters + a bf16 copy need ~36 GB;
 #                    memory_analysis puts 12 at 14.5 GB of v5e's 15.75 GB
 TRAIN_LAYERS, TRAIN_SEQ, TRAIN_BATCH = 1, 2048, 4  # AdamW: ~16 B/param
 TP_LAYERS = 8  # f32 one-chip copy (7.6 GB) + its TP shard on device 0
+SSD_TOL = 2e-4  # ssd kernel at "highest" vs its reference on the host; the
+#                 chip read 1.53e-4 at an output of 53 (PERF.md)
 
 
 def log(phase: str, **kv) -> None:
@@ -50,16 +52,6 @@ def yi6b(n_layers: int, **kw):
 
 def reduced(cfg) -> str:
     return f"n_layers:32->{cfg.n_layers}"
-
-
-def init_params(cfg, ctx, seed: int):
-    """Random weights from ``seed``, made in one compiled program: run op by
-    op, the initializer's temporaries outgrow HBM beside 10 GB of weights."""
-    import jax
-
-    from repro.models import init_lm
-
-    return jax.jit(lambda k: init_lm(k, cfg, ctx))(jax.random.PRNGKey(seed))
 
 
 def requests(cfg, n: int, max_new: int, seed: int):
@@ -92,6 +84,7 @@ def phase_serve(cfg, *, seed: int, n_requests=4, max_new=8, slots=4,
                 capacity=64) -> None:
     """Continuous-batching decode; greedy tokens must equal the wave
     engine's on the same params (the oracle ContinuousEngine promises)."""
+    from repro.launch.serve import init_params
     from repro.mesh.api import ParallelCtx
     from repro.serving import ContinuousEngine, ServeEngine
 
@@ -153,18 +146,25 @@ def phase_stencil(grid: str, domain: str, comm_mode: str, steps=8) -> None:
     assert rc == 0, f"stencil {grid} {domain} {comm_mode} mismatched"
 
 
-def check_kernel(name, fn, ref, args, tol, *, relative=False, **extra):
-    """``fn`` compiles to a Mosaic kernel and agrees with ``ref`` within
-    ``tol`` (times the reference's largest magnitude when ``relative``)."""
+def check_kernel(name, fn, ref, args, tol, *, precision=None,
+                 ref_on_host=False, **extra):
+    """``fn`` compiles to a Mosaic kernel and agrees with ``ref`` element by
+    element, ``|got - want| <= tol``.  Both are traced at matmul
+    ``precision`` when one is given; ``ref_on_host`` runs ``ref`` on the
+    host's CPU device, whose ``exp`` is exact to f32 rounding."""
+    import contextlib
+
     import jax
 
     t0 = time.perf_counter()
-    compiled = jax.jit(fn).lower(*args).compile()
+    ref_args = jax.device_put(args, jax.devices("cpu")[0]) if ref_on_host \
+        else args
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        compiled = jax.jit(fn).lower(*args).compile()
+        want = np.asarray(jax.jit(ref)(*ref_args), np.float32)
     got = np.asarray(compiled(*args), np.float32)
-    want = np.asarray(jax.jit(ref)(*args), np.float32)
     err = float(np.max(np.abs(got - want)))
-    if relative:
-        tol *= float(np.max(np.abs(want)))
     log("kernel", name=name, **extra, max_err=err, tol=tol,
         tpu_custom_call=has_kernel(compiled),
         wall_s=time.perf_counter() - t0)
@@ -201,15 +201,17 @@ def phase_kernels(seed: int) -> None:
                  partial(attention_ref, causal=True), (q, k, v), 3e-2,
                  shape="b1.s2048.h32/4.d128.bf16")
 
-    # mamba2-2.7b SSD: 80 heads x seq 2048 x headdim 64, state 128, f32;
-    # f32 operands take the MXU's default (bf16) passes, so the error is
-    # relative to the output's scale
+    # mamba2-2.7b SSD: 80 heads x seq 2048 x headdim 64, state 128, f32,
+    # with f32 MXU passes.  The sequential reference runs on the host: the
+    # chip's exp errs by ~5e-6 relative, and 2048 chained decays make the
+    # reference there off by ~1e-2, a hundred times the kernel's error
     BH, S, Dh, Dst = 80, 2048, 64, 128
     x, B, C = nrm((BH, S, Dh)), nrm((BH, S, Dst)), nrm((BH, S, Dst))
     dt = jax.nn.softplus(nrm((BH, S)) - 4.0)
     A = -jnp.exp(nrm((BH, 1)))
     check_kernel("ssd", partial(ssd_scan, use_pallas=True), ssd_ref,
-                 (x, dt, B, C, A), 2e-2, relative=True,
+                 (x, dt, B, C, A), SSD_TOL, precision="highest",
+                 ref_on_host=True,
                  shape="bh80.s2048.dh64.n128.f32")
 
     check_kernel("stencil", partial(stencil_step, use_pallas=True),
@@ -283,26 +285,27 @@ def check_router_tick(seed: int, ticks=12) -> None:
 def phase_tp_decode(cfg, comm_mode: str, *, seed: int, n_requests=4,
                     max_new=8, slots=4, capacity=64) -> None:
     """TP continuous decode over mesh (1, 4) against the one-chip engine on
-    device 0, same params, f32 at highest matmul precision."""
+    device 0, same params, f32 at highest matmul precision.  The TP weights
+    are made sharded, as ``launch.serve --mesh 1,4`` makes them; the
+    one-chip engine gets them gathered onto device 0."""
     import jax
 
     from repro.launch.mesh import make_mesh
+    from repro.launch.serve import init_params
     from repro.launch.steps import build_continuous_serve
-    from repro.mesh.api import ParallelCtx
     from repro.serving import ContinuousEngine
 
     t0 = time.perf_counter()
-    dev0 = jax.devices()[0]
     with jax.default_matmul_precision("highest"):
-        params = jax.device_put(init_params(cfg, ParallelCtx(), seed), dev0)
-        with ContinuousEngine(cfg, params, batch_slots=slots,
-                              capacity=capacity) as one:
-            want = drain(one, requests(cfg, n_requests, max_new, seed))
         mesh = make_mesh((1, 4), ("data", "model"))
         rt = build_continuous_serve(cfg, mesh, comm_mode=comm_mode,
                                     batch_slots=slots, capacity=capacity)
-        tp_params = jax.device_put(params, rt["param_sharding"])
+        tp_params = init_params(cfg, rt["ctx"], seed, rt["param_sharding"])
         head = tp_params["head"]
+        params = jax.device_put(tp_params, jax.devices()[0])
+        with ContinuousEngine(cfg, params, batch_slots=slots,
+                              capacity=capacity) as one:
+            want = drain(one, requests(cfg, n_requests, max_new, seed))
         spread = {s.device for s in head.addressable_shards}
         with ContinuousEngine(cfg, tp_params, runtime=rt) as eng:
             caches = jax.tree.leaves(eng.caches)[0]

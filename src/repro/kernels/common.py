@@ -50,7 +50,11 @@ def pallas_call(kernel, *, out_shape, interpret: bool = False,
     kernel never sees.  And a primitive that mixes a block with a constant
     of the kernel fails its own check; a kernel without a grid is therefore
     interpreted by running its body on ``jax.new_ref`` refs instead, traced
-    like any other code.
+    like any other code.  So off the TPU a gridless kernel under
+    ``shard_map`` (the router tick) never reaches the Pallas interpreter:
+    its CPU tests check the kernel body as plain JAX, and only a TPU run
+    checks its ``pallas_call``.  ``tests/test_kernels.py`` fails once the
+    interpreter carries ``vma`` itself, so both workarounds can go.
     """
     from jax.experimental import pallas as pl
 

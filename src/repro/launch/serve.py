@@ -99,6 +99,16 @@ def validate_comm(cfg, mesh, dims, args) -> int:
     return 0
 
 
+def init_params(cfg, ctx, seed: int = 0, sharding=None):
+    """Random weights from ``seed``, made in one compiled program and laid
+    out by ``sharding`` (the runtime's ``param_sharding``) as they are made.
+    Run op by op, the initializer's temporaries outgrow HBM beside the
+    weights; made whole on one device and then placed, a model that only
+    fits sharded would not fit at all."""
+    return jax.jit(lambda k: init_lm(k, cfg, ctx),
+                   out_shardings=sharding)(jax.random.PRNGKey(seed))
+
+
 def _submit_all(eng, cfg, n_requests, max_new, seed=0):
     rng = np.random.RandomState(seed)
     for uid in range(n_requests):
@@ -149,15 +159,14 @@ def main(argv=None):
         rt = build_continuous_serve(cfg, mesh, comm_mode=args.comm_mode,
                                     batch_slots=args.slots,
                                     capacity=args.capacity)
-        params = init_lm(jax.random.PRNGKey(0), cfg, rt["ctx"])
-        params = jax.device_put(params, rt["param_sharding"])
+        params = init_params(cfg, rt["ctx"], sharding=rt["param_sharding"])
         eng = ContinuousEngine(cfg, params, runtime=rt)
         if rt["pool"] is not None:
             print(f"[serve] persistent channels: "
                   f"{sorted(rt['pool'].ports().items())}")
     else:
         ctx = ParallelCtx()
-        params = init_lm(jax.random.PRNGKey(0), cfg, ctx)
+        params = init_params(cfg, ctx)
         cls = ServeEngine if args.engine == "wave" else ContinuousEngine
         eng = cls(cfg, params, ctx=ctx, batch_slots=args.slots,
                   capacity=args.capacity)

@@ -240,3 +240,40 @@ def test_attention_chunked_matches_dense(Sq, Skv, window):
     want = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------ interpreter under check_vma
+
+
+def test_interpreter_still_needs_vma_workaround():
+    """The Pallas interpreter traces a kernel without ``vma``, so a
+    gridless kernel mixing a block with a constant fails under
+    ``shard_map(check_vma=True)``; ``kernels.common.pallas_call`` runs it
+    on refs instead.  When the plain call stops failing, the workarounds
+    in ``kernels.common.pallas_call`` are dead code: delete them."""
+    from jax.experimental import pallas as pl
+    from jax.sharding import Mesh
+    from jax.sharding import PartitionSpec as P
+
+    from repro.compat import vma_of
+    from repro.kernels.common import pallas_call
+
+    def kern(x_ref, o_ref):
+        o_ref[...] = x_ref[...] + 1.0
+
+    def plain(x):
+        out = jax.ShapeDtypeStruct(x.shape, x.dtype, vma=vma_of(x))
+        return pl.pallas_call(kern, out_shape=out, interpret=True)(x)
+
+    def wrapped(x):
+        out = jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return pallas_call(kern, out_shape=out, interpret=True)(x)
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("x",))
+    smap = lambda f: jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x"),
+                                           out_specs=P("x")))
+    x = jnp.arange(16 * 128, dtype=jnp.float32).reshape(16, 128)
+    with pytest.raises(ValueError, match="varying manual axes"):
+        smap(plain)(x)
+    np.testing.assert_array_equal(np.asarray(smap(wrapped)(x)),
+                                  np.asarray(x) + 1.0)
