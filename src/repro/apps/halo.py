@@ -42,7 +42,6 @@ from ..core.overlap import (
     halo_exchange_2d_finish,
     halo_exchange_2d_start,
 )
-from ..obs import trace as obs
 
 #: the tag halo wire traffic is accounted under (TransportStats.by_tag)
 HALO_TAG = "halo"
@@ -113,10 +112,6 @@ class HaloExchange:
         """Launch the four neighbour permutes; returns the in-flight slabs
         (tagged ``"halo"`` in the backend's stats)."""
         t = self.resolve_transport(x, transport)
-        if obs.TRACING:
-            obs.emit("halo.start", tag=self.spec.stats_tag,
-                     grid=list(self.grid), tile=list(x.shape),
-                     transport=t.name)
         return halo_exchange_2d_start(
             x, self.comm, grid=self.grid, halo=self.halo,
             transport=t, tag=self.spec.stats_tag,
@@ -124,9 +119,6 @@ class HaloExchange:
 
     def finish(self, x, inflight):
         """Assemble the halo-padded tile from ``x`` + the in-flight slabs."""
-        if obs.TRACING:
-            obs.emit("halo.finish", tag=self.spec.stats_tag,
-                     grid=list(self.grid))
         return halo_exchange_2d_finish(
             x, inflight, self.comm, grid=self.grid, halo=self.halo
         )

@@ -121,20 +121,26 @@ class DistributedStencil:
         overlap window; only the one-point boundary ring consumes the
         received slabs.  Every point is the same f32 expression as
         :meth:`step_reference` computes, so the two schedules agree bit
-        for bit on every backend.
+        for bit on every backend.  The four regions carry the named
+        scopes ``halo.start``, ``stencil.interior``, ``halo.finish`` and
+        ``stencil.assemble`` in the compiled program's op metadata.
         """
         he = self.halo_schedule
-        inflight = he.start(x, transport)
-        inner = stencil_interior(
-            x, use_pallas=self.use_pallas, interpret=self.interpret
-        )
-        padded = he.finish(x, inflight)
-        out = jnp.zeros_like(x)
-        out = out.at[1:-1, 1:-1].set(inner)
-        out = out.at[0, :].set(_sweep(padded[:3, :])[0])
-        out = out.at[-1, :].set(_sweep(padded[-3:, :])[0])
-        out = out.at[:, 0].set(_sweep(padded[:, :3])[:, 0])
-        out = out.at[:, -1].set(_sweep(padded[:, -3:])[:, 0])
+        with jax.named_scope("halo.start"):
+            inflight = he.start(x, transport)
+        with jax.named_scope("stencil.interior"):
+            inner = stencil_interior(
+                x, use_pallas=self.use_pallas, interpret=self.interpret
+            )
+        with jax.named_scope("halo.finish"):
+            padded = he.finish(x, inflight)
+        with jax.named_scope("stencil.assemble"):
+            out = jnp.zeros_like(x)
+            out = out.at[1:-1, 1:-1].set(inner)
+            out = out.at[0, :].set(_sweep(padded[:3, :])[0])
+            out = out.at[-1, :].set(_sweep(padded[-3:, :])[0])
+            out = out.at[:, 0].set(_sweep(padded[:, :3])[:, 0])
+            out = out.at[:, -1].set(_sweep(padded[:, -3:])[:, 0])
         return out
 
     # -- multi-step runs ---------------------------------------------------
@@ -158,18 +164,20 @@ class DistributedStencil:
 
     def jitted(self, mesh=None, *, n_steps: int = 1, overlapped: bool = True,
                transport=None):
-        """jit(shard_map) callable: (n, nx, ny) stacked tiles -> same."""
+        """jit(shard_map) callable: (n, nx, ny) stacked tiles -> same;
+        its program is named ``stencil_dispatch``."""
         mesh = mesh or self.make_mesh()
         names = self.comm.axis_names
         spec = P(names[0]) if len(names) == 1 else P(names)
 
-        def fn(tiles):
+        def stencil_dispatch(tiles):
             return self.run(
                 tiles[0], n_steps, overlapped=overlapped, transport=transport
             )[None]
 
         return jax.jit(
-            jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec)
+            jax.shard_map(stencil_dispatch, mesh=mesh, in_specs=spec,
+                          out_specs=spec)
         )
 
     # -- host-side domain plumbing ----------------------------------------

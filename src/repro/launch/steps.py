@@ -299,7 +299,7 @@ def build_continuous_serve(cfg: ModelConfig, mesh, *, comm_mode: str = "smi",
         migrate_gather,
         migrate_scatter,
         open_migration,
-        reset_slot,
+        serve_reset_slot,
     )
 
     ctx = make_ctx(mesh, model_axis="model", batch_axes=(),
@@ -322,7 +322,7 @@ def build_continuous_serve(cfg: ModelConfig, mesh, *, comm_mode: str = "smi",
     store_specs = fsdp_storage_specs(pspecs, plan, batch_axes) if fsdp else pspecs
     cspecs = lm_cache_specs(cfg, ctx, shard_batch=False)
 
-    def serve_step(params, caches, token, pos):
+    def serve_decode_step(params, caches, token, pos):
         return lm_decode_step(params, caches, token, pos, cfg, ctx,
                               gather_logits=False, fsdp_plan=plan)
 
@@ -334,7 +334,7 @@ def build_continuous_serve(cfg: ModelConfig, mesh, *, comm_mode: str = "smi",
     cache_sh = _sh(mesh, cspecs)
     step = jax.jit(
         jax.shard_map(
-            serve_step, mesh=mesh,
+            serve_decode_step, mesh=mesh,
             in_specs=(store_specs, cspecs, tok_spec, P(None)),
             out_specs=(logit_spec, cspecs), check_vma=False,
         ),
@@ -344,7 +344,7 @@ def build_continuous_serve(cfg: ModelConfig, mesh, *, comm_mode: str = "smi",
     )
 
     reset = jax.jit(
-        jax.shard_map(reset_slot, mesh=mesh, in_specs=(cspecs, P()),
+        jax.shard_map(serve_reset_slot, mesh=mesh, in_specs=(cspecs, P()),
                       out_specs=cspecs, check_vma=False),
         in_shardings=(cache_sh, None), out_shardings=cache_sh,
         donate_argnums=(0,),

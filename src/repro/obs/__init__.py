@@ -2,10 +2,12 @@
 monitoring (DESIGN.md §11).
 
 Three parts: :mod:`~repro.obs.trace` (the per-process ring-buffer event
-tracer every layer emits into), :mod:`~repro.obs.export` (Chrome-trace /
-Perfetto rendering with a netsim-predicted overlay), and
+tracer every layer emits into, and the runtime :class:`~repro.obs.trace.span`
+that also lands in the profiler's trace), :mod:`~repro.obs.export`
+(Chrome-trace / Perfetto rendering with a netsim-predicted overlay), and
 :mod:`~repro.obs.metrics` (counter/gauge registry snapshotting live
-``TransportStats`` plus drift gauges against ``netsim.predict_*``).
+``TransportStats`` and published runtime records, plus drift gauges
+against ``netsim.predict_*``).
 """
 
 from . import trace
@@ -16,11 +18,12 @@ from .export import (
     write_chrome_trace,
 )
 from .metrics import REGISTRY, MetricsRegistry, get_registry
-from .trace import Tracer
+from .trace import Tracer, span
 
 __all__ = [
     "trace",
     "Tracer",
+    "span",
     "to_chrome_trace",
     "parse_chrome_trace",
     "write_chrome_trace",

@@ -9,6 +9,12 @@ Snapshots read the *live* stats objects, so the numbers are exactly the
 trace-time counters the netsim predictions are asserted against
 (``tests/test_obs.py`` checks equality to the byte).
 
+Live records are the runtime side: an object with a ``snapshot()`` that
+keeps what happened at run time, published under a name
+(:meth:`MetricsRegistry.publish`; the serving engine's request and tick
+record).  :func:`runtime_counters` holds the process-wide counts no single
+call reports: JIT compilations begun and Python GC pause time.
+
 Drift gauges turn the bench-only ``--validate-sim`` 2x gate into a
 continuously-sampled metric: :meth:`MetricsRegistry.drift` records the
 symmetric prediction ratio ``max(pred/meas, meas/pred)`` — computed by the
@@ -19,6 +25,9 @@ record set, returning the worst ratio (== ``validate``'s).
 """
 
 from __future__ import annotations
+
+import gc
+import time
 
 
 def _num(x):
@@ -41,6 +50,7 @@ class MetricsRegistry:
         self.counters: dict = {}
         self.gauges: dict = {}
         self._transports: dict = {}  # name -> live Transport
+        self.records: dict = {}      # name -> live record (has snapshot())
 
     # ---------------------------------------------------------- writers
 
@@ -54,6 +64,11 @@ class MetricsRegistry:
         """Register a live transport; its stats are read at snapshot time
         (re-tracking a name replaces the previous instance)."""
         self._transports[name] = transport
+
+    def publish(self, name: str, record):
+        """Register a live record; its ``snapshot()`` is read at snapshot
+        time (publishing a name again replaces the previous record)."""
+        self.records[name] = record
 
     # ------------------------------------------------------------ drift
 
@@ -101,8 +116,9 @@ class MetricsRegistry:
         }
 
     def snapshot(self) -> dict:
-        """The whole registry as one JSON-safe dict."""
-        return {
+        """The whole registry as one JSON-safe dict (``records`` only where
+        a record is published)."""
+        snap = {
             "counters": dict(self.counters),
             "gauges": dict(self.gauges),
             "transports": {
@@ -111,11 +127,16 @@ class MetricsRegistry:
                 for name, t in self._transports.items()
             },
         }
+        if self.records:
+            snap["records"] = {name: r.snapshot()
+                               for name, r in self.records.items()}
+        return snap
 
     def clear(self):
         self.counters.clear()
         self.gauges.clear()
         self._transports.clear()
+        self.records.clear()
 
 
 #: the process-default registry the benchmark drivers write into
@@ -124,3 +145,59 @@ REGISTRY = MetricsRegistry()
 
 def get_registry() -> MetricsRegistry:
     return REGISTRY
+
+
+# ---------------------------------------------------------------------------
+# process-wide runtime counters
+# ---------------------------------------------------------------------------
+
+#: ``jax.monitoring`` events that mark a compilation begun: a jit cache miss
+#: traces its function, then compiles it (or reads it from the persistent
+#: cache) for the backend
+COMPILE_EVENTS = frozenset({
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/backend_compile_duration",
+})
+
+
+class RuntimeCounters:
+    """Monotonic process-wide counts: ``compiles`` begun (jaxpr traces and
+    backend compiles, from ``jax.monitoring``'s start-of-event scalar),
+    and ``gc_ns``, the host time Python's garbage collector paused the
+    process (``gc.callbacks``).  A reader takes the difference of two
+    readings."""
+
+    def __init__(self):
+        self.compiles = 0
+        self.gc_ns = 0
+        self._gc_t0 = None
+
+    def install(self):
+        import jax.monitoring
+
+        jax.monitoring.register_scalar_listener(self._on_scalar)
+        gc.callbacks.append(self._on_gc)
+
+    def _on_scalar(self, event, value, **kw):
+        if event in COMPILE_EVENTS:
+            self.compiles += 1
+
+    def _on_gc(self, phase, info):
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+        elif self._gc_t0 is not None:
+            self.gc_ns += time.perf_counter_ns() - self._gc_t0
+            self._gc_t0 = None
+
+
+_RUNTIME: "RuntimeCounters | None" = None
+
+
+def runtime_counters() -> RuntimeCounters:
+    """The process's :class:`RuntimeCounters`, its listeners installed on
+    the first call."""
+    global _RUNTIME
+    if _RUNTIME is None:
+        _RUNTIME = RuntimeCounters()
+        _RUNTIME.install()
+    return _RUNTIME

@@ -28,8 +28,17 @@ Event schema (stable; the exporter embeds it verbatim):
 Timestamps are host ``perf_counter`` times.  SPMD producers emit once per
 *python trace*, not per runtime step — a channel push event marks where the
 schedule staged an element, not a runtime packet (runtime counters live in
-``TransportStats`` and the metrics snapshot).  jax-free by design, so the
-netsim/tuner side can import it before jax initialises.
+``TransportStats`` and the metrics snapshot).
+
+Runtime spans are the other kind of producer: :class:`span` wraps host work
+that runs every step (the serving engine's tick phases).  A span always
+enters a ``jax.profiler.TraceAnnotation``, so under the profiler it lands in
+the ``.xplane.pb`` on the device trace's clock; with a tracer enabled it is
+also recorded as one schema event whose ``attrs["dur"]`` the exporter
+renders as a complete slice.
+
+jax-free at import by design, so the netsim/tuner side can import it before
+jax initialises; the profiler is imported on the first span.
 """
 
 from __future__ import annotations
@@ -139,3 +148,43 @@ def enabled(capacity: int = 65536, clock=time.perf_counter):
     finally:
         _TRACER = prev
         TRACING = prev is not None
+
+
+#: ``jax.profiler.TraceAnnotation``, imported on the first span
+_ANNOTATION = None
+
+
+class span:
+    """Runtime span: ``with span("serve.admit"): ...``.
+
+    ``name`` is a dotted ``layer.phase``; ``ids`` (a request's ``uid``, a
+    slot) go into the profiler annotation and the schema event's ``attrs``.
+    With the profiler off the annotation costs about a microsecond; with no
+    tracer enabled nothing else is recorded.
+    """
+
+    __slots__ = ("name", "ids", "_ann", "_t0")
+
+    def __init__(self, name: str, **ids):
+        self.name = name
+        self.ids = ids
+        self._t0 = None
+
+    def __enter__(self):
+        global _ANNOTATION
+        if _ANNOTATION is None:
+            from jax.profiler import TraceAnnotation
+
+            _ANNOTATION = TraceAnnotation
+        self._ann = _ANNOTATION(self.name, **self.ids)
+        self._ann.__enter__()
+        if _TRACER is not None:
+            self._t0 = _TRACER.now()
+        return self
+
+    def __exit__(self, *exc):
+        t = _TRACER
+        if t is not None and self._t0 is not None:
+            t.event(self.name, ts=self._t0, dur=t.now() - self._t0, **self.ids)
+        self._ann.__exit__(*exc)
+        return False

@@ -4,12 +4,12 @@ from .continuous import (
     copy_slot,
     open_migration,
     pack_slot,
-    reset_slot,
+    serve_reset_slot,
     slot_nbytes,
     unpack_slot,
 )
 
 __all__ = [
-    "ServeEngine", "Request", "ContinuousEngine", "reset_slot", "copy_slot",
-    "pack_slot", "unpack_slot", "slot_nbytes", "open_migration",
+    "ServeEngine", "Request", "ContinuousEngine", "serve_reset_slot",
+    "copy_slot", "pack_slot", "unpack_slot", "slot_nbytes", "open_migration",
 ]

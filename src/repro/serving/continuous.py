@@ -9,9 +9,10 @@ is the production loop:
   generalises bit-identically from the scalar wave case), so every slot
   advances independently;
 * **per-slot admission/invalidation** — a request lands in *any* free
-  slot; :func:`reset_slot` invalidates exactly that slot's rows across
-  every cache leaf (``slot_pos`` rows back to -1, state to 0) without
-  touching its batch-mates, so nothing ever leaks between requests;
+  slot; :func:`serve_reset_slot` invalidates exactly that slot's rows
+  across every cache leaf (``slot_pos`` rows back to -1, state to 0)
+  without touching its batch-mates, so nothing ever leaks between
+  requests;
 * **prefill/decode overlap** — newly admitted slots replay their prompts
   through the same decode step their batch-mates are generating in (the
   per-slot cursor), so there is no prefill barrier;
@@ -30,9 +31,18 @@ is the production loop:
 Migration always rides the lossless static schedule on a raw wire: the
 image is reinterpreted bytes (bf16 KV, int32 positions, f32 recurrent
 state) and a lossy or reordering wire would corrupt it.
+
+Every tick runs inside a ``serve.tick`` span with one child span a phase
+(``serve.admit``, ``serve.prepare``, ``serve.dispatch``, ``serve.sample``,
+``serve.harvest``; ``repro.obs.trace.span``), and the programs it
+dispatches carry stable names (``serve_decode_step``, ``serve_reset_slot``,
+``serve_greedy``).  The engine's :class:`~repro.serving.record.EngineRecord`
+times each request and each phase on the host, always.
 """
 
 from __future__ import annotations
+
+import time
 
 import jax
 import jax.numpy as jnp
@@ -41,8 +51,11 @@ from jax import lax
 
 from ..mesh.api import ParallelCtx
 from ..models import lm_caches, lm_decode_step
+from ..obs import metrics as obs_metrics
+from ..obs.trace import span
 from ..parallel import ledger
 from .engine import Request
+from .record import EngineRecord
 
 #: the stats tag migration traffic tallies under (pool-prefixed ->
 #: "serve.migrate"); gather and scatter legs share it
@@ -69,7 +82,7 @@ def _is_slot_pos(path) -> bool:
     return any(getattr(k, "key", None) == "slot_pos" for k in path)
 
 
-def reset_slot(caches, slot):
+def serve_reset_slot(caches, slot):
     """Invalidate one batch slot across every cache leaf: its ``slot_pos``
     rows go to -1 (no valid entry) and all other state to 0.  The other
     slots' rows are untouched — this is the per-slot cache invalidation
@@ -83,6 +96,28 @@ def reset_slot(caches, slot):
         return lax.dynamic_update_slice_in_dim(leaf, row, slot, bdim)
 
     return jax.tree_util.tree_map_with_path(one, caches)
+
+
+@jax.jit
+def serve_greedy(logits):
+    """The greedy pick of every slot: the best vocabulary entry (axis 1)."""
+    return jnp.argmax(logits, axis=1)
+
+
+class _phase(span):
+    """One phase of a tick: its span, then its end stamped onto ``bounds``,
+    the tick's host times (``time.time_ns()``) that the record keeps."""
+
+    __slots__ = ("bounds",)
+
+    def __init__(self, name: str, bounds: list):
+        super().__init__(name)
+        self.bounds = bounds
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self.bounds.append(time.time_ns())
+        return False
 
 
 def copy_slot(caches, src, dst):
@@ -200,11 +235,16 @@ class ContinuousEngine:
     the same params: each slot's computation depends only on its own row
     (per-slot positions, per-row cache masking), so batch-mates — and
     when they were admitted — cannot perturb it.
+
+    The engine's :attr:`record` is published in
+    ``repro.obs.metrics.REGISTRY`` under ``name``, replacing the record of
+    an earlier engine of that name.
     """
 
     def __init__(self, cfg, params, *, ctx: ParallelCtx | None = None,
                  batch_slots: int = 4, capacity: int = 128,
-                 eos: int | None = None, runtime: dict | None = None):
+                 eos: int | None = None, runtime: dict | None = None,
+                 name: str = "serve"):
         self.cfg = cfg
         self.params = params
         self.eos = eos
@@ -225,11 +265,13 @@ class ContinuousEngine:
             self.capacity = capacity
             self.caches = lm_caches(cfg, batch_slots, capacity=capacity,
                                     ctx=self.ctx)
-            self._step = jax.jit(
-                lambda p, c, t, pos: lm_decode_step(p, c, t, pos, cfg,
-                                                    self.ctx)
-            )
-            self._reset = jax.jit(reset_slot, donate_argnums=(0,))
+            ctx = self.ctx
+
+            def serve_decode_step(p, c, t, pos):
+                return lm_decode_step(p, c, t, pos, cfg, ctx)
+
+            self._step = jax.jit(serve_decode_step)
+            self._reset = jax.jit(serve_reset_slot, donate_argnums=(0,))
             # single-device "migration": the packed image round-trips
             # locally (the comm legs need a TP runtime)
             self._mig_start = jax.jit(pack_slot)
@@ -244,10 +286,16 @@ class ContinuousEngine:
         self.steps_done = 0
         self.admit_step: dict[int, int] = {}   # uid -> tick admitted
         self.finish_step: dict[int, int] = {}  # uid -> tick completed
+        # host-time record of requests and ticks, found by name in the
+        # metrics registry (a new engine of the same name replaces it)
+        self.record = EngineRecord()
+        self._runtime = obs_metrics.runtime_counters()
+        obs_metrics.REGISTRY.publish(name, self.record)
 
     # -- queue / admission ---------------------------------------------------
 
     def submit(self, req: Request):
+        self.record.stamp(req.uid, "submit", time.time_ns())
         self.queue.append(req)
 
     @staticmethod
@@ -261,7 +309,9 @@ class ContinuousEngine:
         for i in range(self.B):
             if self.slot_req[i] is None and self.queue:
                 req = self.queue.pop(0)
-                self.caches = self._reset(self.caches, np.int32(i))
+                with span("serve.reset", uid=req.uid, slot=i):
+                    self.caches = self._reset(self.caches, np.int32(i))
+                self.record.stamp(req.uid, "admit", time.time_ns())
                 self.slot_req[i] = req
                 self.pos[i] = 0
                 self.cursor[i] = 0
@@ -275,21 +325,41 @@ class ContinuousEngine:
     def tick(self) -> list[Request]:
         """Admit, run ONE decode step for every occupied slot (prompt
         replay and generation overlap in the same step), harvest
-        completions.  Returns the requests completed this tick."""
-        self._admit()
-        if not any(self._active(r) for r in self.slot_req):
-            return []
-        for i, req in enumerate(self.slot_req):
-            if not self._active(req):
-                self._cur[i] = 0
-            elif self.cursor[i] < len(req.prompt):
-                self._cur[i] = req.prompt[int(self.cursor[i])]
-            # else: keep the sampled token from the last tick
-        logits, self.caches = self._step(
-            self.params, self.caches, jnp.asarray(self._cur),
-            jnp.asarray(self.pos),
-        )
-        nxt = np.asarray(jnp.argmax(logits, axis=1))  # (B[, n_cb])
+        completions.  Returns the requests completed this tick.
+
+        Each phase runs in its own span and is timed into the record;
+        ``serve.sample`` is where the host waits for the chip."""
+        rt = self._runtime
+        compiles, gc_ns = rt.compiles, rt.gc_ns
+        t = [time.time_ns()]
+        with span("serve.tick", step=self.steps_done):
+            with _phase("serve.admit", t):
+                self._admit()
+            if not any(self._active(r) for r in self.slot_req):
+                return []
+            with _phase("serve.prepare", t):
+                for i, req in enumerate(self.slot_req):
+                    if not self._active(req):
+                        self._cur[i] = 0
+                    elif self.cursor[i] < len(req.prompt):
+                        self._cur[i] = req.prompt[int(self.cursor[i])]
+                    # else: keep the sampled token from the last tick
+            with _phase("serve.dispatch", t):
+                logits, self.caches = self._step(
+                    self.params, self.caches, jnp.asarray(self._cur),
+                    jnp.asarray(self.pos),
+                )
+            with _phase("serve.sample", t):
+                nxt = np.asarray(serve_greedy(logits))  # (B[, n_cb])
+            with _phase("serve.harvest", t):
+                done = self._harvest(nxt, t[-1])
+        self.record.tick(t, rt.compiles - compiles, rt.gc_ns - gc_ns)
+        return done
+
+    def _harvest(self, nxt, now: int) -> list[Request]:
+        """Advance every active slot by the step's token, append generated
+        tokens (stamped ``now``, when they reached the host), free finished
+        slots."""
         done: list[Request] = []
         for i, req in enumerate(self.slot_req):
             if not self._active(req):
@@ -299,12 +369,15 @@ class ContinuousEngine:
             if self.cursor[i] >= len(req.prompt):
                 tok = nxt[i]
                 req.out.append(tok.tolist() if tok.ndim else int(tok))
+                if len(req.out) == 1:
+                    self.record.stamp(req.uid, "first", now)
                 self._cur[i] = tok
                 hit_eos = (self.eos is not None and np.ndim(tok) == 0
                            and int(tok) == self.eos)
                 if len(req.out) >= req.max_new or hit_eos:
                     req.done = True
                     self.finish_step[req.uid] = self.steps_done + 1
+                    self.record.stamp(req.uid, "finish", now)
                     done.append(req)
                     self.slot_req[i] = None   # freed NOW: no wave barrier
         self.steps_done += 1
@@ -345,13 +418,16 @@ class ContinuousEngine:
         req = self.slot_req[src]
         assert self._active(req), "source slot must hold a request"
         assert self.slot_req[dst] is None, "destination slot must be free"
-        inflight = self._mig_start(self.caches, np.int32(src))
+        with span("serve.migrate.start", uid=req.uid, slot=src):
+            inflight = self._mig_start(self.caches, np.int32(src))
         self.slot_req[src] = _MIGRATING
         self.slot_req[dst] = _MIGRATING
         state = (self.pos[src], self.cursor[src], self._cur[src].copy())
         for _ in range(overlap_ticks):
             self.tick()
-        self.caches = self._mig_finish(self.caches, inflight, np.int32(dst))
+        with span("serve.migrate.finish", uid=req.uid, slot=dst):
+            self.caches = self._mig_finish(self.caches, inflight,
+                                           np.int32(dst))
         self.slot_req[src] = None
         self.slot_req[dst] = req
         self.pos[dst], self.cursor[dst], self._cur[dst] = state
