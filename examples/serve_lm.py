@@ -1,8 +1,8 @@
 """Serve a small model with continuous batching (DESIGN.md §13).
 
 Default: the single-device ContinuousEngine — requests admit into any
-free slot mid-decode, prompts replay through the same step their
-batch-mates generate in.  Uncomment the mesh/comm-mode args to decode
+free slot mid-decode, prompts go into the cache in chunks that ride in
+the same step their batch-mates generate in.  Uncomment the mesh/comm-mode args to decode
 tensor-parallel over persistent SMI channels (one port claim per layer
 tag, held until engine shutdown); add ``--validate-comm`` to byte-check
 the ``serve.*`` channel ledger against the netsim prediction instead.

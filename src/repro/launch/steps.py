@@ -30,6 +30,7 @@ from ..models import (
     lm_cache_specs,
     lm_caches,
     lm_decode_step,
+    lm_mixed_step,
     lm_loss,
     lm_prefill,
     lm_specs,
@@ -281,7 +282,8 @@ def build_continuous_serve(cfg: ModelConfig, mesh, *, comm_mode: str = "smi",
 
     Returns the ``runtime`` dict :class:`~repro.serving.ContinuousEngine`
     consumes: the shard_map'd per-slot decode step (``pos`` is a (B,)
-    vector), the slot-invalidation step, the two migration legs on the
+    vector), the same step carrying one prompt chunk (``mixed_step``,
+    :func:`~repro.models.lm_mixed_step`), the slot-invalidation step, the two migration legs on the
     pool's ``serve.migrate`` gather/scatter channels, and the
     :class:`~repro.channels.ChannelPool` whose persistent port claims
     outlive every trace (released only by ``pool.close()`` / engine
@@ -326,6 +328,12 @@ def build_continuous_serve(cfg: ModelConfig, mesh, *, comm_mode: str = "smi",
         return lm_decode_step(params, caches, token, pos, cfg, ctx,
                               gather_logits=False, fsdp_plan=plan)
 
+    def serve_mixed_step(params, caches, token, pos, chunk_tok, chunk_slot,
+                         chunk_start, chunk_len):
+        return lm_mixed_step(params, caches, token, pos, chunk_tok,
+                             chunk_slot, chunk_start, chunk_len, cfg, ctx,
+                             gather_logits=False, fsdp_plan=plan)
+
     tok_spec = P(None, None) if cfg.n_codebooks > 1 else P(None)
     logit_spec = (
         P(None, "model", None) if cfg.n_codebooks > 1 else P(None, "model")
@@ -340,6 +348,18 @@ def build_continuous_serve(cfg: ModelConfig, mesh, *, comm_mode: str = "smi",
         ),
         in_shardings=(param_sh, cache_sh, None, None),
         out_shardings=(None, cache_sh),
+        donate_argnums=(1,),
+    )
+    # the decode step plus one replicated prompt chunk (dense configs)
+    mixed_step = jax.jit(
+        jax.shard_map(
+            serve_mixed_step, mesh=mesh,
+            in_specs=(store_specs, cspecs, tok_spec, P(None), P(None), P(),
+                      P(), P()),
+            out_specs=(logit_spec, P("model"), cspecs), check_vma=False,
+        ),
+        in_shardings=(param_sh, cache_sh, None, None, None, None, None, None),
+        out_shardings=(None, None, cache_sh),
         donate_argnums=(1,),
     )
 
@@ -392,7 +412,7 @@ def build_continuous_serve(cfg: ModelConfig, mesh, *, comm_mode: str = "smi",
     )
 
     return dict(
-        ctx=ctx, pool=pool, step=step, reset=reset,
+        ctx=ctx, pool=pool, step=step, mixed_step=mixed_step, reset=reset,
         migrate_start=migrate_start, migrate_finish=migrate_finish,
         init_caches=init_caches, batch_slots=batch_slots, capacity=capacity,
         param_sharding=param_sh, cache_sharding=cache_sh,

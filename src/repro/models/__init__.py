@@ -6,6 +6,8 @@ from .model import (
     lm_loss,
     lm_prefill,
     lm_decode_step,
+    lm_mixed_step,
+    chunked_prefill_ok,
     lm_caches,
     lm_cache_specs,
 )
@@ -16,6 +18,8 @@ __all__ = [
     "lm_loss",
     "lm_prefill",
     "lm_decode_step",
+    "lm_mixed_step",
+    "chunked_prefill_ok",
     "lm_caches",
     "lm_cache_specs",
 ]

@@ -253,23 +253,15 @@ def kv_cache_specs(ctx: ParallelCtx, shard_batch: bool = True):
             "slot_pos": P(b, m)}
 
 
-def decode_attention(p, x, cache, pos, cfg, ctx: ParallelCtx):
-    """One decode step.  x: (B, 1, D) replicated over model; ``pos`` is the
-    absolute position of the new token — a scalar (wave decoding: every
-    row at the same position) or a (B,) int array (continuous batching:
-    one position per slot).  Returns (y (B, 1, D), cache')."""
-    B = x.shape[0]
+def _decode_qkv(p, x2d, pos_b, cfg, ctx: ParallelCtx):
+    """Projections of N single-token rows at per-row positions ``pos_b``
+    (N,): every query head (all-gathered over the model axis), and the
+    rows' new K/V.  Returns (q (N, Hp, hd), k_new, v_new (N, 1, Hkv, hd))."""
+    N = x2d.shape[0]
     hd = cfg.hd
     tp = ctx.tp
     H_loc = p["wq"].shape[1] // hd
     Hp = H_loc * tp
-    mask, kv_idx = _head_mask_and_kv_map(cfg, ctx)
-    r = ctx.rank()
-    cap_loc = cache["k"].shape[1]
-    capacity = cap_loc * tp
-    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-
-    x2d = x.reshape(B, -1)
     q_loc = (x2d @ p["wq"])
     k_new = (x2d @ p["wk"])
     v_new = (x2d @ p["wv"])
@@ -277,9 +269,9 @@ def decode_attention(p, x, cache, pos, cfg, ctx: ParallelCtx):
         q_loc = q_loc + p["bq"]
         k_new = k_new + p["bk"]
         v_new = v_new + p["bv"]
-    q_loc = q_loc.reshape(B, 1, H_loc, hd)
-    k_new = k_new.reshape(B, 1, cfg.n_kv_heads, hd)
-    v_new = v_new.reshape(B, 1, cfg.n_kv_heads, hd)
+    q_loc = q_loc.reshape(N, 1, H_loc, hd)
+    k_new = k_new.reshape(N, 1, cfg.n_kv_heads, hd)
+    v_new = v_new.reshape(N, 1, cfg.n_kv_heads, hd)
     if cfg.qk_norm:
         q_loc = rms_norm(q_loc, p["q_norm"], cfg.norm_eps)
         k_new = rms_norm(k_new, p["k_norm"], cfg.norm_eps)
@@ -288,16 +280,25 @@ def decode_attention(p, x, cache, pos, cfg, ctx: ParallelCtx):
 
     # gather all query heads (tiny) so every device scans its cache slice
     if tp > 1:
-        q = gather_sequence(q_loc.reshape(B, H_loc * hd)[None], ctx,
+        q = gather_sequence(q_loc.reshape(N, H_loc * hd)[None], ctx,
                             tag="tp.attn.qkv")
-        q = q.reshape(tp, B, H_loc, hd).transpose(1, 0, 2, 3).reshape(B, Hp, hd)
+        q = q.reshape(tp, N, H_loc, hd).transpose(1, 0, 2, 3).reshape(N, Hp, hd)
     else:
-        q = q_loc.reshape(B, Hp, hd)
+        q = q_loc.reshape(N, Hp, hd)
+    return q, k_new, v_new
 
-    # ring-buffer write, per batch row: global slot = pos % capacity;
-    # shard r owns slots [r*cap_loc, (r+1)*cap_loc)
+
+def _ring_write(cache, k_new, v_new, pos_b, ctx: ParallelCtx, writes=None):
+    """Ring-buffer write, per batch row: global slot = pos % capacity;
+    shard r owns slots [r*cap_loc, (r+1)*cap_loc).  A row whose ``writes``
+    entry is False leaves the cache as it was."""
+    r = ctx.rank()
+    cap_loc = cache["k"].shape[1]
+    capacity = cap_loc * ctx.tp
     g_slot_b = pos_b % capacity
     my_b = jnp.logical_and(g_slot_b >= r * cap_loc, g_slot_b < (r + 1) * cap_loc)
+    if writes is not None:
+        my_b = jnp.logical_and(my_b, writes)
     l_slot_b = jnp.clip(g_slot_b - r * cap_loc, 0, cap_loc - 1)
     write = jnp.logical_and(
         my_b[:, None], jnp.arange(cap_loc)[None, :] == l_slot_b[:, None]
@@ -309,38 +310,149 @@ def decode_attention(p, x, cache, pos, cfg, ctx: ParallelCtx):
         write[:, :, None, None], v_new.astype(cache["v"].dtype), cache["v"]
     )
     slot_pos = jnp.where(write, pos_b[:, None], cache["slot_pos"])
+    return {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
 
+
+def _masked_softmax_attend(s, valid, v, spec: str, ctx: ParallelCtx):
+    """Flash-decoding combine of f32 scores ``s`` (..., k) over this
+    shard's cache rows: masked softmax with the max and the sums psum'd
+    over the model axis, then ``einsum(spec, p, v)``, normalised."""
+    s = jnp.where(valid, s, -1e30)
+    m_loc = s.max(axis=-1)
+    m_g = pmax_tagged(m_loc, ctx, "tp.attn.out")
+    pexp = jnp.exp(s - m_g[..., None])
+    pexp = jnp.where(valid, pexp, 0.0)
+    l_loc = pexp.sum(axis=-1)
+    o_loc = jnp.einsum(spec, pexp, v)
+    l_g = psum_tagged(l_loc, ctx, "tp.attn.out")
+    o_g = psum_tagged(o_loc, ctx, "tp.attn.out")
+    return o_g / jnp.maximum(l_g, 1e-30)[..., None]
+
+
+def _decode_rows_attend(q, cache, pos_b, cfg, ctx: ParallelCtx):
+    """Each row's query (B, Hp, hd) over its own slot's local cache slice,
+    rows at positions <= its own (and inside the window, if any)."""
+    hd = cfg.hd
+    Hp = q.shape[1]
     # partial attention over the local cache slice, all heads
-    kv_sel_k = jnp.take(k_cache, kv_idx_full(cfg, Hp), axis=2)  # (B, cap_loc, Hp, hd)
-    kv_sel_v = jnp.take(v_cache, kv_idx_full(cfg, Hp), axis=2)
+    kv_sel_k = jnp.take(cache["k"], kv_idx_full(cfg, Hp), axis=2)  # (B, cap_loc, Hp, hd)
+    kv_sel_v = jnp.take(cache["v"], kv_idx_full(cfg, Hp), axis=2)
     s = jnp.einsum(
         "bhd,bkhd->bhk", q.astype(jnp.float32) * hd ** -0.5,
         kv_sel_k.astype(jnp.float32),
     )
+    slot_pos = cache["slot_pos"]
     valid = slot_pos >= 0                                    # (B, cap_loc)
     valid = jnp.logical_and(valid, slot_pos <= pos_b[:, None])
     if cfg.local_window is not None:
         valid = jnp.logical_and(
             valid, slot_pos > pos_b[:, None] - cfg.local_window
         )
-    s = jnp.where(valid[:, None, :], s, -1e30)
-    m_loc = s.max(axis=-1)                                   # (B, Hp)
-    m_g = pmax_tagged(m_loc, ctx, "tp.attn.out")
-    pexp = jnp.exp(s - m_g[..., None])
-    pexp = jnp.where(valid[:, None, :], pexp, 0.0)
-    l_loc = pexp.sum(axis=-1)
-    o_loc = jnp.einsum("bhk,bkhd->bhd", pexp, kv_sel_v.astype(jnp.float32))
-    l_g = psum_tagged(l_loc, ctx, "tp.attn.out")
-    o_g = psum_tagged(o_loc, ctx, "tp.attn.out")
-    o = o_g / jnp.maximum(l_g, 1e-30)[..., None]             # (B, Hp, hd)
-    o = o * mask_full(cfg, Hp)[None, :, None].astype(o.dtype)
+    return _masked_softmax_attend(s, valid[:, None, :],
+                                  kv_sel_v.astype(jnp.float32),
+                                  "bhk,bkhd->bhd", ctx)      # (B, Hp, hd)
 
-    # row-parallel out proj: my head slice only, then psum
-    o_my = lax.dynamic_slice_in_dim(o, r * H_loc, H_loc, axis=1)
-    y = (o_my.reshape(B, H_loc * hd).astype(x.dtype)) @ p["wo"]
+
+def _out_proj(p, o, dtype, cfg, ctx: ParallelCtx):
+    """Row-parallel out projection of (N, Hp, hd) heads: padded heads
+    zeroed, my head slice only, then psum.  Returns (N, 1, D)."""
+    N, Hp, hd = o.shape
+    H_loc = Hp // ctx.tp
+    o = o * mask_full(cfg, Hp)[None, :, None].astype(o.dtype)
+    o_my = lax.dynamic_slice_in_dim(o, ctx.rank() * H_loc, H_loc, axis=1)
+    y = (o_my.reshape(N, H_loc * hd).astype(dtype)) @ p["wo"]
     y = psum_tagged(y, ctx, "tp.attn.out")
-    cache = {"k": k_cache, "v": v_cache, "slot_pos": slot_pos}
-    return y.reshape(B, 1, -1), cache
+    return y.reshape(N, 1, -1)
+
+
+def decode_attention(p, x, cache, pos, cfg, ctx: ParallelCtx):
+    """One decode step.  x: (B, 1, D) replicated over model; ``pos`` is the
+    absolute position of the new token — a scalar (wave decoding: every
+    row at the same position) or a (B,) int array (continuous batching:
+    one position per slot).  Returns (y (B, 1, D), cache')."""
+    B = x.shape[0]
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q, k_new, v_new = _decode_qkv(p, x.reshape(B, -1), pos_b, cfg, ctx)
+    cache = _ring_write(cache, k_new, v_new, pos_b, ctx)
+    o = _decode_rows_attend(q, cache, pos_b, cfg, ctx)
+    return _out_proj(p, o, x.dtype, cfg, ctx), cache
+
+
+def _chunk_write(cache, k_new, v_new, slot, start, n, ctx: ParallelCtx):
+    """Write a prompt chunk's first ``n`` K/V rows (C, Hkv, hd) into slot
+    ``slot`` at positions ``start ...``, which lie below the capacity (no
+    row wraps the ring).  Each shard rewrites one window of min(C,
+    cap_loc) of its own rows in that slot (``dynamic_update_slice``),
+    holding the part of the chunk that falls in its row range; the rows
+    past ``n`` and every other slot stay as they were."""
+    C = k_new.shape[0]
+    cap_loc = cache["k"].shape[1]
+    W = min(C, cap_loc)
+    lo = ctx.rank() * cap_loc                     # this shard's first row
+    off = jnp.clip(start - lo, 0, cap_loc - W)    # the window's first row
+    row_pos = lo + off + jnp.arange(W)            # positions it holds
+    j = row_pos - start                           # chunk row of each
+    ok = jnp.logical_and(j >= 0, j < n)
+    jc = jnp.clip(j, 0, C - 1)
+
+    def put(leaf, new):
+        at = (slot, off) + (0,) * (leaf.ndim - 2)
+        old = lax.dynamic_slice(leaf, at, (1, W) + leaf.shape[2:])
+        keep = ok.reshape((1, W) + (1,) * (leaf.ndim - 2))
+        row = jnp.where(keep, new[None].astype(leaf.dtype), old)
+        return lax.dynamic_update_slice(leaf, row, at)
+
+    return {"k": put(cache["k"], jnp.take(k_new, jc, axis=0)),
+            "v": put(cache["v"], jnp.take(v_new, jc, axis=0)),
+            "slot_pos": put(cache["slot_pos"], row_pos)}
+
+
+def _chunk_attend(q, cache, slot, start, cfg, ctx: ParallelCtx):
+    """Causal attention of a prompt chunk's queries (C, Hp, hd), at
+    positions ``start ...``, over slot ``slot``'s local cache rows (which
+    already hold the chunk).  GQA by grouping the queries of one KV head,
+    so the slot's K/V are read once, unexpanded."""
+    C, Hp, hd = q.shape
+    H, Hkv = cfg.n_heads, cfg.n_kv_heads
+    assert H % Hkv == 0, "chunked prefill needs whole GQA groups"
+    k = lax.dynamic_index_in_dim(cache["k"], slot, 0, keepdims=False)
+    v = lax.dynamic_index_in_dim(cache["v"], slot, 0, keepdims=False)
+    slot_pos = lax.dynamic_index_in_dim(cache["slot_pos"], slot, 0,
+                                        keepdims=False)   # (cap_loc,)
+    qg = (q[:, :H].astype(jnp.float32) * hd ** -0.5).reshape(
+        C, Hkv, H // Hkv, hd)
+    s = jnp.einsum("cngd,knd->cngk", qg, k.astype(jnp.float32))
+    qpos = start + jnp.arange(C)
+    valid = jnp.logical_and(slot_pos[None, :] >= 0,
+                            slot_pos[None, :] <= qpos[:, None])  # (C, cap_loc)
+    o = _masked_softmax_attend(s, valid[:, None, None, :],
+                               v.astype(jnp.float32), "cngk,knd->cngd", ctx)
+    return jnp.pad(o.reshape(C, H, hd), ((0, 0), (0, Hp - H), (0, 0)))
+
+
+def mixed_attention(p, x, cache, pos, chunk, cfg, ctx: ParallelCtx):
+    """One decode step for B slots plus one prompt chunk of C tokens for
+    one slot, every projection over the B + C rows together.
+
+    x: (B + C, 1, D), the decode rows first; ``pos`` (B,): each decode
+    row's position, negative for a slot that is not decoding (it writes
+    nothing); ``chunk = (slot, start, n)``: the chunk's slot, the position
+    of its first token and how many of its C rows are prompt tokens (the
+    rest are padding and write nothing).  Returns (y (B + C, 1, D),
+    cache')."""
+    slot, start, n = chunk
+    B = pos.shape[0]
+    C = x.shape[0] - B
+    pos_b = jnp.asarray(pos, jnp.int32)
+    qpos = jnp.concatenate([pos_b, start + jnp.arange(C, dtype=jnp.int32)])
+    q, k_new, v_new = _decode_qkv(p, x.reshape(B + C, -1), qpos, cfg, ctx)
+    cache = _ring_write(cache, k_new[:B], v_new[:B], pos_b, ctx,
+                        writes=pos_b >= 0)
+    cache = _chunk_write(cache, k_new[B:, 0], v_new[B:, 0], slot, start, n,
+                         ctx)
+    o = jnp.concatenate([_decode_rows_attend(q[:B], cache, pos_b, cfg, ctx),
+                         _chunk_attend(q[B:], cache, slot, start, cfg, ctx)])
+    return _out_proj(p, o, x.dtype, cfg, ctx), cache
 
 
 def kv_idx_full(cfg, Hp: int):

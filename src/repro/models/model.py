@@ -133,6 +133,19 @@ def _dt(cfg):
 # ------------------------------------------------------------ train / loss
 
 
+def _step_params(params, cfg, ctx: ParallelCtx, fsdp_plan):
+    """The weights one step reads: cast once to the compute
+    dtype, the top-level leaves gathered under an FSDP plan."""
+    pf = _cast(params, _dt(cfg))
+    if fsdp_plan is not None:
+        from ..mesh.api import fsdp_gather
+
+        for key in ("embed", "head", "embed_cb", "head_cb", "final_norm"):
+            if key in pf:
+                pf[key] = fsdp_gather(pf[key], fsdp_plan[key], ctx)
+    return pf
+
+
 def lm_loss(
     params,
     tokens,      # (B, S) int32 (or (B, S, n_cb))
@@ -149,13 +162,7 @@ def lm_loss(
 ):
     """Causal-LM loss (mean CE over valid labels) + MoE aux loss."""
     tp = ctx.tp
-    pf = _cast(params, _dt(cfg))
-    if fsdp_plan is not None:
-        from ..mesh.api import fsdp_gather
-
-        for key in ("embed", "head", "embed_cb", "head_cb", "final_norm"):
-            if key in pf:
-                pf[key] = fsdp_gather(pf[key], fsdp_plan[key], ctx)
+    pf = _step_params(params, cfg, ctx, fsdp_plan)
     x = embed_tokens_sp(pf, tokens, cfg, ctx, extra_embeds=extra_embeds)
     x, aux = apply_stack(pf["stack"], x, cfg, ctx, interp=interp, remat=remat,
                          fsdp_plan=None if fsdp_plan is None else fsdp_plan["stack"])
@@ -224,20 +231,37 @@ def lm_prefill(params, tokens, cfg, ctx: ParallelCtx, *, capacity: int,
     attention block-wise); returns final hidden states, sequence-sharded.
 
     NOTE: serving-grade prefill would also populate the KV cache; the
-    serve engine replays prefill through decode steps for cache build at
-    small scale, while the 32k prefill shape benchmarks this compute path.
+    continuous engine builds it in prompt chunks that ride in its decode
+    step (:func:`lm_mixed_step`), or by replaying the prompt through
+    decode steps, while the 32k prefill shape benchmarks this compute
+    path.
     """
-    pf = _cast(params, _dt(cfg))
-    if fsdp_plan is not None:
-        from ..mesh.api import fsdp_gather
-
-        for key in ("embed", "head", "embed_cb", "head_cb", "final_norm"):
-            if key in pf:
-                pf[key] = fsdp_gather(pf[key], fsdp_plan[key], ctx)
+    pf = _step_params(params, cfg, ctx, fsdp_plan)
     x = embed_tokens_sp(pf, tokens, cfg, ctx, extra_embeds=extra_embeds)
     x, _ = apply_stack(pf["stack"], x, cfg, ctx, interp=interp, remat="none",
                        fsdp_plan=None if fsdp_plan is None else fsdp_plan["stack"])
     return rms_norm(x, pf["final_norm"], cfg.norm_eps)
+
+
+def _serve_logits(pf, x, cfg, ctx: ParallelCtx, gather_logits: bool):
+    """Head of the serving steps: x (N, D) final hidden rows -> f32
+    logits, full (N, V[, n_cb]) when ``gather_logits``, else the local
+    vocab shard (N, V_loc[, n_cb])."""
+    if cfg.n_codebooks > 1:
+        logit_loc = jnp.stack(
+            [x @ pf["head_cb"][cb] for cb in range(cfg.n_codebooks)], axis=-1
+        )  # (N, V_loc, n_cb)
+    elif cfg.tie_embeddings:
+        logit_loc = x @ pf["embed"].T
+    else:
+        logit_loc = x @ pf["head"]
+    if not gather_logits:
+        return logit_loc.astype(jnp.float32)
+    # gather the vocab shards: (V_loc, ...) -> (V, ...)
+    logits = gather_sequence(jnp.moveaxis(logit_loc, 1, 0), ctx,
+                             tag="tp.loss.gather")
+    logits = jnp.moveaxis(logits, 0, 1)                     # (N, V[, n_cb])
+    return logits.astype(jnp.float32)
 
 
 def lm_decode_step(params, caches, token, pos, cfg, ctx: ParallelCtx,
@@ -247,13 +271,7 @@ def lm_decode_step(params, caches, token, pos, cfg, ctx: ParallelCtx,
     Returns (logits, caches'): full (B, V[, n_cb]) when ``gather_logits``,
     else the local vocab shard (B, V_loc[, n_cb]) for shard_map out_specs
     to assemble (avoids the in-region gather)."""
-    pf = _cast(params, _dt(cfg))
-    if fsdp_plan is not None:
-        from ..mesh.api import fsdp_gather
-
-        for key in ("embed", "head", "embed_cb", "head_cb", "final_norm"):
-            if key in pf:
-                pf[key] = fsdp_gather(pf[key], fsdp_plan[key], ctx)
+    pf = _step_params(params, cfg, ctx, fsdp_plan)
     if cfg.n_codebooks > 1:
         emb = sum(
             _embed_partial(pf["embed_cb"][cb], token[:, cb], ctx)
@@ -265,22 +283,49 @@ def lm_decode_step(params, caches, token, pos, cfg, ctx: ParallelCtx,
     x, caches = decode_stack(pf["stack"], caches, x, pos, cfg, ctx,
                              fsdp_plan=None if fsdp_plan is None else fsdp_plan["stack"])
     x = rms_norm(x, pf["final_norm"], cfg.norm_eps)[:, 0]   # (B, D)
+    return _serve_logits(pf, x, cfg, ctx, gather_logits), caches
 
-    if cfg.n_codebooks > 1:
-        logit_loc = jnp.stack(
-            [x @ pf["head_cb"][cb] for cb in range(cfg.n_codebooks)], axis=-1
-        )  # (B, V_loc, n_cb)
-    elif cfg.tie_embeddings:
-        logit_loc = x @ pf["embed"].T
-    else:
-        logit_loc = x @ pf["head"]
-    if not gather_logits:
-        return logit_loc.astype(jnp.float32), caches
-    # gather the vocab shards: (V_loc, ...) -> (V, ...)
-    logits = gather_sequence(jnp.moveaxis(logit_loc, 1, 0), ctx,
-                             tag="tp.loss.gather")
-    logits = jnp.moveaxis(logits, 0, 1)                     # (B, V[, n_cb])
-    return logits.astype(jnp.float32), caches
+
+def chunked_prefill_ok(cfg) -> bool:
+    """Whether :func:`lm_mixed_step` can take a prompt in chunks: every
+    layer is dense attention over the whole context with one token
+    stream.  Recurrent state (SSM, RG-LRU) takes its tokens one at a time,
+    a window's ring is smaller than a chunk may reach, MoE capacity drops
+    depend on the batch's rows, and codebook streams have no chunk
+    input."""
+    return (all(kind == "attn" for kind in cfg.layer_pattern)
+            and cfg.local_window is None and cfg.n_codebooks == 1)
+
+
+def lm_mixed_step(params, caches, token, pos, chunk_tok, chunk_slot,
+                  chunk_start, chunk_len, cfg, ctx: ParallelCtx,
+                  *, gather_logits: bool = True, fsdp_plan=None):
+    """One decode step for the B slots plus one prompt chunk for one slot,
+    in the same matmuls (B + C rows; for :func:`chunked_prefill_ok`
+    configs).
+
+    token, pos: (B,) as for :func:`lm_decode_step`, except that a slot
+    whose ``pos`` is negative is not decoding and writes nothing to the
+    cache.  chunk_tok: (C,) prompt tokens, the first ``chunk_len`` real,
+    for slot ``chunk_slot`` at positions ``chunk_start ...`` (below the
+    capacity).  Only the chunk's last real row goes through the head.
+
+    Returns (logits (B, V), chunk_logits (V,), caches'), the local vocab
+    shards (V_loc) unless ``gather_logits``."""
+    B = token.shape[0]
+    pf = _step_params(params, cfg, ctx, fsdp_plan)
+    emb = _embed_partial(pf["embed"], jnp.concatenate([token, chunk_tok]), ctx)
+    x = psum_tagged(emb, ctx, "tp.embed")[:, None, :].astype(_dt(cfg))
+    x, caches = decode_stack(
+        pf["stack"], caches, x, pos, cfg, ctx,
+        fsdp_plan=None if fsdp_plan is None else fsdp_plan["stack"],
+        chunk=(chunk_slot, chunk_start, chunk_len),
+    )                                                       # (B + C, 1, D)
+    rows = jnp.concatenate(
+        [x[:B, 0], lax.dynamic_index_in_dim(x[:, 0], B + chunk_len - 1, 0)])
+    rows = rms_norm(rows, pf["final_norm"], cfg.norm_eps)   # (B + 1, D)
+    logits = _serve_logits(pf, rows, cfg, ctx, gather_logits)
+    return logits[:B], logits[B], caches
 
 
 def lm_caches(cfg, B: int, capacity: int, ctx: ParallelCtx):

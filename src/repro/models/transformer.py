@@ -23,6 +23,7 @@ from .attention import (
     init_attention,
     init_kv_cache,
     kv_cache_specs,
+    mixed_attention,
 )
 from .common import rms_norm
 from .mlp import apply_mlp, apply_mlp_replicated, init_mlp, mlp_specs
@@ -147,11 +148,20 @@ def block_cache_specs(kind: str, ctx, shard_batch: bool = True):
     raise ValueError(kind)
 
 
-def decode_block(p, kind: str, x, cache, pos, cfg, ctx: ParallelCtx):
+def decode_block(p, kind: str, x, cache, pos, cfg, ctx: ParallelCtx,
+                 chunk=None):
+    """One decode step of one block; with ``chunk`` (dense attention
+    blocks only) the rows past the B decode rows are a prompt chunk
+    (:func:`~repro.models.attention.mixed_attention`)."""
+    assert chunk is None or kind == "attn", \
+        f"no chunked prefill through {kind!r} blocks"
     if kind in ("attn", "moe"):
-        y, cache = decode_attention(
-            p["attn"], rms_norm(x, p["norm1"], cfg.norm_eps), cache, pos, cfg, ctx
-        )
+        xn = rms_norm(x, p["norm1"], cfg.norm_eps)
+        if chunk is None:
+            y, cache = decode_attention(p["attn"], xn, cache, pos, cfg, ctx)
+        else:
+            y, cache = mixed_attention(p["attn"], xn, cache, pos, chunk, cfg,
+                                       ctx)
         x = x + y
         h = rms_norm(x, p["norm2"], cfg.norm_eps)
         if kind == "attn":
@@ -316,7 +326,8 @@ def stack_cache_specs(cfg, ctx, shard_batch: bool = True):
     return {"periods": stacked, "rem": remainder}
 
 
-def decode_stack(params, caches, x, pos, cfg, ctx: ParallelCtx, *, fsdp_plan=None):
+def decode_stack(params, caches, x, pos, cfg, ctx: ParallelCtx, *, fsdp_plan=None,
+                 chunk=None):
     from ..mesh.api import fsdp_gather
 
     pattern = cfg.pattern
@@ -332,7 +343,8 @@ def decode_stack(params, caches, x, pos, cfg, ctx: ParallelCtx, *, fsdp_plan=Non
             pp = fsdp_gather(pp, period_plan, ctx)
         new_cc = []
         for j in range(period):
-            x, c = decode_block(pp[j], pattern[j], x, cc[j], pos, cfg, ctx)
+            x, c = decode_block(pp[j], pattern[j], x, cc[j], pos, cfg, ctx,
+                                chunk)
             new_cc.append(c)
         return x, tuple(new_cc)
 
@@ -346,6 +358,7 @@ def decode_stack(params, caches, x, pos, cfg, ctx: ParallelCtx, *, fsdp_plan=Non
     for j, p in enumerate(params["rem"]):
         if fsdp_plan is not None:
             p = fsdp_gather(p, fsdp_plan["rem"][j], ctx)
-        x, c = decode_block(p, pattern[j], x, caches["rem"][j], pos, cfg, ctx)
+        x, c = decode_block(p, pattern[j], x, caches["rem"][j], pos, cfg, ctx,
+                            chunk)
         new_rem.append(c)
     return x, {"periods": new_stacked, "rem": tuple(new_rem)}

@@ -13,9 +13,16 @@ is the production loop:
   across every cache leaf (``slot_pos`` rows back to -1, state to 0)
   without touching its batch-mates, so nothing ever leaks between
   requests;
-* **prefill/decode overlap** — newly admitted slots replay their prompts
-  through the same decode step their batch-mates are generating in (the
-  per-slot cursor), so there is no prefill barrier;
+* **prefill/decode overlap** — a tick carries at most one prompt chunk
+  of up to :data:`PREFILL_CHUNK` tokens for one slot, in the same step
+  and the same matmuls as its batch-mates' decode rows
+  (:func:`~repro.models.lm_mixed_step`), so there is no prefill barrier.
+  Slots whose prompts are not yet in the cache take chunks in admission
+  order, and the first token comes from the last chunk.  Where the
+  config has recurrent, windowed, MoE or codebook layers
+  (:func:`~repro.models.chunked_prefill_ok`), or a prompt does not fit
+  in the capacity, the slot replays its prompt one token a tick through
+  its decode row instead (the per-slot cursor);
 * **persistent channels** — under tensor parallelism the decode step's
   layer channels come from a :class:`~repro.channels.ChannelPool`
   threaded through ``ParallelCtx(channels=pool)``: one
@@ -35,9 +42,10 @@ state) and a lossy or reordering wire would corrupt it.
 Every tick runs inside a ``serve.tick`` span with one child span a phase
 (``serve.admit``, ``serve.prepare``, ``serve.dispatch``, ``serve.sample``,
 ``serve.harvest``; ``repro.obs.trace.span``), and the programs it
-dispatches carry stable names (``serve_decode_step``, ``serve_reset_slot``,
-``serve_greedy``).  The engine's :class:`~repro.serving.record.EngineRecord`
-times each request and each phase on the host, always.
+dispatches carry stable names (``serve_decode_step``,
+``serve_mixed_step``, ``serve_reset_slot``, ``serve_greedy``).  The
+engine's :class:`~repro.serving.record.EngineRecord` times each request
+and each phase on the host, always.
 """
 
 from __future__ import annotations
@@ -50,7 +58,12 @@ import numpy as np
 from jax import lax
 
 from ..mesh.api import ParallelCtx
-from ..models import lm_caches, lm_decode_step
+from ..models import (
+    chunked_prefill_ok,
+    lm_caches,
+    lm_decode_step,
+    lm_mixed_step,
+)
 from ..obs import metrics as obs_metrics
 from ..obs.trace import span
 from ..parallel import ledger
@@ -60,6 +73,19 @@ from .record import EngineRecord
 #: the stats tag migration traffic tallies under (pool-prefixed ->
 #: "serve.migrate"); gather and scatter legs share it
 MIGRATE_TAG = "migrate"
+
+#: prompt tokens a tick takes into the cache for one slot, riding in the
+#: decode step (Sarathi-Serve's stall-free batching, arXiv:2403.02310).
+#: The decode step already reads every weight once a tick (yi-6b's 0.82 GB
+#: of bf16, ~1.0 ms at a v5e's 819 GB/s); 32 decode rows plus 128 chunk
+#: rows are 160 rows of matmul, ~130 GFLOP, ~0.7 ms at its 197 TFLOP/s,
+#: so the chunk's rows reuse that read instead of adding a tick each.  The
+#: chunk's f32 scores over one 2048-row slot are 33.6 MB a layer.  On a
+#: v5e the step at 32 x 2048 rows took no more device time with a chunk
+#: of 128 than with one of 64, and no more than without one (PERF.md §6).
+#: One fixed width (``min(PREFILL_CHUNK, capacity)``), so the mixed step
+#: compiles once.
+PREFILL_CHUNK = 128
 
 #: sentinel occupying a slot whose cache image is in flight (migration):
 #: not decodable, not admittable
@@ -99,8 +125,12 @@ def serve_reset_slot(caches, slot):
 
 
 @jax.jit
-def serve_greedy(logits):
-    """The greedy pick of every slot: the best vocabulary entry (axis 1)."""
+def serve_greedy(logits, chunk_logits=None):
+    """The greedy pick of every slot: the best vocabulary entry (axis 1);
+    with a prompt chunk's ``chunk_logits`` (V,), its pick follows as row
+    B."""
+    if chunk_logits is not None:
+        logits = jnp.concatenate([logits, chunk_logits[None]])
     return jnp.argmax(logits, axis=1)
 
 
@@ -255,6 +285,7 @@ class ContinuousEngine:
             self.capacity = runtime["capacity"]
             self.caches = runtime["init_caches"]()
             self._step = runtime["step"]
+            self._mixed = runtime["mixed_step"]
             self._reset = runtime["reset"]
             self._mig_start = runtime["migrate_start"]
             self._mig_finish = runtime["migrate_finish"]
@@ -270,7 +301,11 @@ class ContinuousEngine:
             def serve_decode_step(p, c, t, pos):
                 return lm_decode_step(p, c, t, pos, cfg, ctx)
 
+            def serve_mixed_step(p, c, t, pos, ct, cs, c0, cn):
+                return lm_mixed_step(p, c, t, pos, ct, cs, c0, cn, cfg, ctx)
+
             self._step = jax.jit(serve_decode_step)
+            self._mixed = jax.jit(serve_mixed_step)
             self._reset = jax.jit(serve_reset_slot, donate_argnums=(0,))
             # single-device "migration": the packed image round-trips
             # locally (the comm legs need a TP runtime)
@@ -283,6 +318,13 @@ class ContinuousEngine:
         self.cursor = np.zeros(B, dtype=np.int64)   # per-slot prompt cursor
         tok_shape = (B, cfg.n_codebooks) if cfg.n_codebooks > 1 else (B,)
         self._cur = np.zeros(tok_shape, dtype=np.int32)
+        # prompt chunk width; 0 where every prompt is replayed
+        self.chunk = (min(PREFILL_CHUNK, self.capacity)
+                      if chunked_prefill_ok(cfg) else 0)
+        # admitted requests whose prompts are not yet all in the cache,
+        # taken in chunks, in admission order
+        self._filling: list[Request] = []
+        self._decoding = np.zeros(B, dtype=bool)   # slots decoding a tick
         self.steps_done = 0
         self.admit_step: dict[int, int] = {}   # uid -> tick admitted
         self.finish_step: dict[int, int] = {}  # uid -> tick completed
@@ -316,6 +358,8 @@ class ContinuousEngine:
                 self.pos[i] = 0
                 self.cursor[i] = 0
                 self._cur[i] = 0
+                if self.chunk and 0 < len(req.prompt) <= self.capacity:
+                    self._filling.append(req)
                 self.admit_step[req.uid] = self.steps_done
                 n += 1
         return n
@@ -323,8 +367,8 @@ class ContinuousEngine:
     # -- the decode tick -----------------------------------------------------
 
     def tick(self) -> list[Request]:
-        """Admit, run ONE decode step for every occupied slot (prompt
-        replay and generation overlap in the same step), harvest
+        """Admit, run ONE step for every occupied slot (a prompt chunk, or
+        prompt replay, and generation overlap in the same step), harvest
         completions.  Returns the requests completed this tick.
 
         Each phase runs in its own span and is timed into the record;
@@ -338,50 +382,93 @@ class ContinuousEngine:
             if not any(self._active(r) for r in self.slot_req):
                 return []
             with _phase("serve.prepare", t):
-                for i, req in enumerate(self.slot_req):
-                    if not self._active(req):
-                        self._cur[i] = 0
-                    elif self.cursor[i] < len(req.prompt):
-                        self._cur[i] = req.prompt[int(self.cursor[i])]
-                    # else: keep the sampled token from the last tick
+                chunk, replayed = self._prepare()
             with _phase("serve.dispatch", t):
-                logits, self.caches = self._step(
-                    self.params, self.caches, jnp.asarray(self._cur),
-                    jnp.asarray(self.pos),
-                )
+                tok = jnp.asarray(self._cur)
+                if chunk is None:
+                    logits, self.caches = self._step(
+                        self.params, self.caches, tok, jnp.asarray(self.pos))
+                    chunk_logits = None
+                else:
+                    _, slot, start, n, chunk_tok = chunk
+                    pos = jnp.asarray(np.where(self._decoding, self.pos, -1))
+                    logits, chunk_logits, self.caches = self._mixed(
+                        self.params, self.caches, tok, pos,
+                        jnp.asarray(chunk_tok), np.int32(slot),
+                        np.int32(start), np.int32(n))
             with _phase("serve.sample", t):
-                nxt = np.asarray(serve_greedy(logits))  # (B[, n_cb])
+                nxt = np.asarray(serve_greedy(logits, chunk_logits))
             with _phase("serve.harvest", t):
-                done = self._harvest(nxt, t[-1])
-        self.record.tick(t, rt.compiles - compiles, rt.gc_ns - gc_ns)
+                done = self._harvest(nxt, t[-1], chunk)
+        self.record.tick(t, rt.compiles - compiles, rt.gc_ns - gc_ns,
+                         0 if chunk is None else chunk[3], replayed)
         return done
 
-    def _harvest(self, nxt, now: int) -> list[Request]:
-        """Advance every active slot by the step's token, append generated
-        tokens (stamped ``now``, when they reached the host), free finished
-        slots."""
+    def _prepare(self):
+        """Each decoding slot's input token, and the tick's prompt chunk:
+        the next ``min(chunk, left)`` prompt tokens of the first filling
+        request in a slot, as ``(req, slot, start, n, tokens)`` (tokens
+        padded to the chunk width), or None.  Returns the chunk and the
+        number of prompt tokens replayed through decode rows."""
+        slot_of = {id(r): i for i, r in enumerate(self.slot_req)
+                   if self._active(r)}
+        filling = {id(r) for r in self._filling}
+        replayed = 0
+        for i, req in enumerate(self.slot_req):
+            self._decoding[i] = self._active(req) and id(req) not in filling
+            if not self._decoding[i]:
+                self._cur[i] = 0
+            elif self.cursor[i] < len(req.prompt):
+                self._cur[i] = req.prompt[int(self.cursor[i])]
+                replayed += 1
+            # else: keep the sampled token from the last tick
+        req = next((r for r in self._filling if id(r) in slot_of), None)
+        if req is None:
+            return None, replayed
+        slot = slot_of[id(req)]
+        start = int(self.cursor[slot])
+        part = req.prompt[start:start + self.chunk]
+        tokens = np.zeros(self.chunk, dtype=np.int32)
+        tokens[:len(part)] = part
+        return (req, slot, start, len(part), tokens), replayed
+
+    def _harvest(self, nxt, now: int, chunk) -> list[Request]:
+        """Advance every decoding slot by the step's token and the chunk's
+        slot by its tokens, append generated tokens (stamped ``now``, when
+        they reached the host), free finished slots."""
         done: list[Request] = []
         for i, req in enumerate(self.slot_req):
-            if not self._active(req):
+            if not self._decoding[i]:
                 continue
             self.pos[i] += 1
             self.cursor[i] += 1
             if self.cursor[i] >= len(req.prompt):
-                tok = nxt[i]
-                req.out.append(tok.tolist() if tok.ndim else int(tok))
-                if len(req.out) == 1:
-                    self.record.stamp(req.uid, "first", now)
-                self._cur[i] = tok
-                hit_eos = (self.eos is not None and np.ndim(tok) == 0
-                           and int(tok) == self.eos)
-                if len(req.out) >= req.max_new or hit_eos:
-                    req.done = True
-                    self.finish_step[req.uid] = self.steps_done + 1
-                    self.record.stamp(req.uid, "finish", now)
-                    done.append(req)
-                    self.slot_req[i] = None   # freed NOW: no wave barrier
+                self._emit(i, req, nxt[i], now, done)
+        if chunk is not None:
+            req, slot, _, n, _ = chunk
+            self.pos[slot] += n
+            self.cursor[slot] += n
+            if self.cursor[slot] >= len(req.prompt):
+                self._filling = [r for r in self._filling if r is not req]
+                self._emit(slot, req, nxt[self.B], now, done)
         self.steps_done += 1
         return done
+
+    def _emit(self, i: int, req: Request, tok, now: int, done: list):
+        """Slot ``i``'s request takes generated token ``tok``; a finished
+        request goes to ``done`` and its slot is freed."""
+        req.out.append(tok.tolist() if tok.ndim else int(tok))
+        if len(req.out) == 1:
+            self.record.stamp(req.uid, "first", now)
+        self._cur[i] = tok
+        hit_eos = (self.eos is not None and np.ndim(tok) == 0
+                   and int(tok) == self.eos)
+        if len(req.out) >= req.max_new or hit_eos:
+            req.done = True
+            self.finish_step[req.uid] = self.steps_done + 1
+            self.record.stamp(req.uid, "finish", now)
+            done.append(req)
+            self.slot_req[i] = None   # freed NOW: no wave barrier
 
     def run(self, *, max_steps: int = 256, arrivals=None) -> list[Request]:
         """Drain the queue; returns completed requests.

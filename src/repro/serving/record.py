@@ -16,7 +16,9 @@ placed on the device trace.
 * Per tick that ran a decode step: its start and the host time of each
   phase of :data:`PHASES`, in order, plus the JIT compilations begun and
   the garbage-collector pause inside it
-  (:func:`repro.obs.metrics.runtime_counters`).
+  (:func:`repro.obs.metrics.runtime_counters`), and the prompt tokens it
+  took into the cache: in its prompt chunk (``prefill_tokens``) and
+  through decode rows, one a slot (``replay_tokens``).
 """
 
 from __future__ import annotations
@@ -50,10 +52,12 @@ class EngineRecord:
                 self._requests.popitem(last=False)
         r[STAMPS.index(which)] = ns
 
-    def tick(self, bounds, compiles: int, gc_ns: int) -> None:
+    def tick(self, bounds, compiles: int, gc_ns: int, prefill_tokens: int,
+             replay_tokens: int) -> None:
         """One tick: ``bounds`` are the host times at its start and at the
         end of each phase (``len(PHASES) + 1`` stamps)."""
-        self._ticks.append((*bounds, compiles, gc_ns))
+        self._ticks.append((*bounds, compiles, gc_ns, prefill_tokens,
+                            replay_tokens))
 
     def __len__(self) -> int:
         return len(self._ticks)
@@ -61,13 +65,16 @@ class EngineRecord:
     def snapshot(self) -> dict:
         """JSON-safe copy: ``requests`` as a list of ``{"uid", *STAMPS}``
         and ``ticks`` as columns, ``start_ns``, one ``<phase>_ns``
-        duration per phase, ``compiles`` and ``gc_ns``."""
+        duration per phase, ``compiles``, ``gc_ns``, ``prefill_tokens``
+        and ``replay_tokens``."""
         n = len(PHASES) + 1
         ticks = {"start_ns": [t[0] for t in self._ticks]}
         for k, phase in enumerate(PHASES):
             ticks[f"{phase}_ns"] = [t[k + 1] - t[k] for t in self._ticks]
         ticks["compiles"] = [t[n] for t in self._ticks]
         ticks["gc_ns"] = [t[n + 1] for t in self._ticks]
+        ticks["prefill_tokens"] = [t[n + 2] for t in self._ticks]
+        ticks["replay_tokens"] = [t[n + 3] for t in self._ticks]
         return {
             "requests": [{"uid": uid, **dict(zip(STAMPS, r))}
                          for uid, r in self._requests.items()],

@@ -19,6 +19,13 @@ Four contracts:
   survive trace exits and garbage collection, and are released only by
   engine shutdown / ``pool.close()``.
 
+* **chunked prefill** — a dense-attention config takes each prompt that
+  fits in the cache into it in chunks of up to ``PREFILL_CHUNK`` tokens,
+  one chunk a tick in admission order, riding in the decode step; the
+  greedy tokens still equal the wave oracle's, single-device and
+  tensor-parallel, and a slot waiting for its chunks has no cache row
+  written.  Other configs replay the prompt one token a tick.
+
 Plus the serving twin of the train-step accounting regression:
 ``netsim.predict_decode_step_stats`` equals the traced channel ledger to
 the byte per ``serve.*`` tag (the ``launch/serve --validate-comm``
@@ -36,7 +43,15 @@ from repro.configs import get_arch, smoke
 from repro.mesh.api import ParallelCtx
 from repro.models import init_lm, lm_caches
 from repro.serving import ContinuousEngine, Request, ServeEngine
-from repro.serving.continuous import copy_slot, pack_slot, unpack_slot
+from repro.serving.continuous import (
+    PREFILL_CHUNK,
+    copy_slot,
+    pack_slot,
+    unpack_slot,
+)
+
+C = PREFILL_CHUNK
+CAP = 4 * C   # cache rows in the chunked-prefill tests: room for 3C + 5
 
 
 @pytest.fixture(scope="module")
@@ -124,6 +139,125 @@ def test_slot_churn_no_cache_row_leaks(engine_setup):
         assert eng.admit_step[uid] < eng.finish_step[uid]
 
 
+# -------------------------------------------------------- chunked prefill
+
+
+def _prompt(n, seed=0):
+    return [int(t) for t in np.random.RandomState(seed).randint(1, 500, n)]
+
+
+def _wave(cfg, params, prompts, *, slots=2, capacity=CAP, max_new=4):
+    """{uid: tokens} from the wave oracle."""
+    wave = ServeEngine(cfg, params, batch_slots=slots, capacity=capacity)
+    for r in _reqs(prompts, max_new):
+        wave.submit(r)
+    return {r.uid: r.out for r in wave.run(max_steps=2000)}
+
+
+def _continuous(cfg, params, prompts, *, slots=2, capacity=CAP, max_new=4,
+                runtime=None):
+    """{uid: tokens} from a continuous engine, and its tick record."""
+    kw = (dict(runtime=runtime) if runtime is not None
+          else dict(batch_slots=slots, capacity=capacity))
+    with ContinuousEngine(cfg, params, **kw) as eng:
+        for r in _reqs(prompts, max_new):
+            eng.submit(r)
+        got = {r.uid: r.out for r in eng.run(max_steps=2000)}
+    return got, eng.record.snapshot()["ticks"]
+
+
+def _wave_and_continuous(cfg, params, prompts, **kw):
+    """The wave oracle's tokens, the continuous engine's and its record."""
+    return (_wave(cfg, params, prompts, **kw),
+            *_continuous(cfg, params, prompts, **kw))
+
+
+@pytest.mark.parametrize("n", [1, C - 1, C, C + 1, 3 * C + 5])
+def test_chunked_prefill_matches_wave_engine(engine_setup, n):
+    """A prompt of n tokens taken in chunks, beside a batch-mate that
+    decodes through its chunks: both outputs equal the wave oracle's, and
+    every prompt token went in through a chunk."""
+    cfg, params = engine_setup
+    prompts = [_prompt(n), [5, 7, 9]]
+    want, got, ticks = _wave_and_continuous(cfg, params, prompts)
+    assert got == want
+    assert sum(ticks["prefill_tokens"]) == n + 3
+    assert sum(ticks["replay_tokens"]) == 0
+    assert max(ticks["prefill_tokens"]) <= C
+
+
+def test_chunked_prefill_below_the_chunk_width(engine_setup):
+    """At a capacity below C the chunk is the capacity wide; a prompt that
+    fills the cache is one chunk."""
+    cfg, params = engine_setup
+    prompts = [_prompt(32), _prompt(20, 1), [4]]
+    want, got, ticks = _wave_and_continuous(cfg, params, prompts,
+                                            capacity=32, max_new=1)
+    assert got == want
+    assert sorted(t for t in ticks["prefill_tokens"] if t) == [1, 20, 32]
+
+
+def test_prefill_fifo_takes_chunks_in_admission_order(engine_setup):
+    """Two long prompts admitted in one tick: the first takes a chunk each
+    tick until its prompt is in, then the second; a short one admitted
+    with them waits its turn too.  Outputs equal the wave oracle's."""
+    cfg, params = engine_setup
+    lens = [2 * C + 3, C + 7, 2]
+    prompts = [_prompt(n, k) for k, n in enumerate(lens)]
+    want, got, ticks = _wave_and_continuous(cfg, params, prompts, slots=3)
+    assert got == want
+    assert ticks["prefill_tokens"][:6] == [C, C, 3, C, 7, 2]
+
+    eng = ContinuousEngine(cfg, params, batch_slots=3, capacity=CAP)
+    for r in _reqs(prompts):
+        eng.submit(r)
+    firsts = {}
+    while len(firsts) < 3:
+        k = eng.steps_done
+        eng.tick()
+        for i, req in enumerate(eng.slot_req):
+            if req is not None and req.out and req.uid not in firsts:
+                firsts[req.uid] = k
+    assert firsts == {0: 2, 1: 4, 2: 5}
+
+
+@pytest.mark.parametrize("variant", [
+    dict(local_window=8),
+    dict(pattern=("attn", "ssm"), ssm_state=16, ssm_headdim=16),
+], ids=["windowed", "attn-ssm"])
+def test_replay_path_where_chunks_cannot_go(engine_setup, variant):
+    """The same prompts under a windowed or an attention-plus-SSM config:
+    no chunk, every prompt token replayed through a decode row, and the
+    outputs equal the wave oracle's."""
+    cfg = engine_setup[0].scaled(**variant)
+    params = init_lm(jax.random.PRNGKey(0), cfg, ParallelCtx())
+    prompts = [_prompt(n) for n in (1, C - 1, C + 1)]
+    want, got, ticks = _wave_and_continuous(cfg, params, prompts)
+    assert got == want
+    assert sum(ticks["prefill_tokens"]) == 0
+    assert sum(ticks["replay_tokens"]) == 1 + 2 * C
+
+
+def test_waiting_slot_gets_no_cache_row(engine_setup):
+    """While one slot takes its chunks, another slot waiting for its own
+    has no cache row written by the step, and the filling slot holds
+    exactly the positions its chunks took in, in every layer."""
+    cfg, params = engine_setup
+    eng = ContinuousEngine(cfg, params, batch_slots=3, capacity=CAP)
+    for r in _reqs([_prompt(3 * C), _prompt(2 * C, 1), [5, 7]]):
+        eng.submit(r)
+    for k in range(1, 4):
+        eng.tick()
+        for path, leaf in jax.tree_util.tree_leaves_with_path(eng.caches):
+            if "slot_pos" not in jax.tree_util.keystr(path):
+                continue
+            rows = np.asarray(leaf)           # (layers, slots, capacity)
+            assert (rows[:, 1] == -1).all()
+            want = np.where(np.arange(CAP) < k * C, np.arange(CAP), -1)
+            assert (rows[:, 0] == want).all()
+    assert eng.slot_req[1].out == []
+
+
 # ------------------------------------------------------------- migration
 
 
@@ -209,6 +343,29 @@ def test_tp_continuous_matches_wave_oracle(dims, backend, devices8):
             eng.submit(r)
         got = {r.uid: r.out for r in eng.run(max_steps=200)}
     assert got == want, f"{backend} on {dims} diverged from wave oracle"
+
+
+@pytest.mark.parametrize("capacity,n", [(2 * C, C + 6), (10 * C, 2 * C + 6)],
+                         ids=["shard-below-chunk", "chunk-straddles-shards"])
+def test_tp_chunked_prefill_matches_wave_oracle(devices8, capacity, n):
+    """On a ring of 8 the sequence-sharded cache takes a prompt longer than
+    C in chunks, each shard writing the part in its own rows: shards of
+    fewer rows than C, and shards of 1.25 C rows, which a chunk straddles.
+    The greedy tokens equal the single-device wave engine's."""
+    from repro.launch.mesh import make_mesh
+    from repro.launch.steps import build_continuous_serve
+
+    cfg = _tp_cfg()
+    params = init_lm(jax.random.PRNGKey(0), cfg, ParallelCtx())
+    prompts = [_prompt(n), [11, 3], _prompt(40, 1)]
+    rt = build_continuous_serve(cfg, make_mesh((1, 8), ("data", "model")),
+                                comm_mode="smi:static", batch_slots=2,
+                                capacity=capacity)
+    want = _wave(cfg, params, prompts, capacity=capacity, max_new=3)
+    got, ticks = _continuous(cfg, jax.device_put(params, rt["param_sharding"]),
+                             prompts, max_new=3, runtime=rt)
+    assert got == want
+    assert sum(ticks["prefill_tokens"]) == n + 2 + 40
 
 
 def test_persistent_pool_lifecycle(devices8):

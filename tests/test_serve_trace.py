@@ -30,6 +30,7 @@ from repro.obs import metrics
 from repro.obs import trace as obs
 from repro.obs.export import to_chrome_trace
 from repro.serving import ContinuousEngine, Request
+from repro.serving.continuous import PREFILL_CHUNK as C
 from repro.serving.record import PHASES
 
 SPANS = ["serve.tick", *(f"serve.{p}" for p in PHASES)]
@@ -50,9 +51,9 @@ def _drain(eng, max_ticks=200):
     raise AssertionError("engine did not drain")
 
 
-def _warm(cfg, params, slots=2):
+def _warm(cfg, params, slots=2, capacity=32):
     """An engine whose programs have all run once at its shapes."""
-    eng = ContinuousEngine(cfg, params, batch_slots=slots, capacity=32)
+    eng = ContinuousEngine(cfg, params, batch_slots=slots, capacity=capacity)
     eng.submit(Request(uid=-1, prompt=[1, 2], max_new=2))
     _drain(eng)
     return eng
@@ -61,22 +62,29 @@ def _warm(cfg, params, slots=2):
 # ------------------------------------------------------------- the record
 
 
-@pytest.mark.parametrize("n", [1, 5])
-def test_first_token_n_ticks_after_admission(engine_setup, n):
-    """A prompt of n tokens replays one a tick: its first token comes out
-    of the n-th tick counted from the one that admitted it, and the
-    record's stamps fall inside those two ticks."""
-    cfg, params = engine_setup
-    eng = _warm(cfg, params)
+def _first_token_tick(eng, n):
+    """Submit a prompt of n tokens, tick until it is served; the tick that
+    gave its first token."""
     eng.submit(Request(uid=7, prompt=list(range(3, 3 + n)), max_new=3))
     first_tick = None
-    for _ in range(n + 3):
+    while not eng.finish_step.get(7):
         k = eng.steps_done
         eng.tick()
         req = eng.record.snapshot()["requests"][-1]
         if first_tick is None and req["first"] is not None:
             first_tick = k
-    assert first_tick - eng.admit_step[7] == n - 1
+    return first_tick
+
+
+@pytest.mark.parametrize("n", [1, 5, C + 1])
+def test_first_token_n_ticks_after_admission(engine_setup, n):
+    """A prompt of n tokens goes into the cache C a tick: its first token
+    comes out of the ceil(n / C)-th tick counted from the one that
+    admitted it, and the record's stamps fall inside those two ticks."""
+    cfg, params = engine_setup
+    eng = _warm(cfg, params, capacity=2 * C)
+    first_tick = _first_token_tick(eng, n)
+    assert first_tick - eng.admit_step[7] == -(-n // C) - 1
     snap = eng.record.snapshot()
     req = next(r for r in snap["requests"] if r["uid"] == 7)
     ticks = snap["ticks"]
@@ -95,6 +103,39 @@ def test_first_token_n_ticks_after_admission(engine_setup, n):
     end_of_sample = start[i] + sum(ticks[f"{p}_ns"][i]
                                    for p in PHASES[:PHASES.index("sample") + 1])
     assert req["first"] == end_of_sample
+
+
+def test_first_token_n_ticks_after_admission_on_replay(engine_setup):
+    """Where chunks cannot go (a windowed config), a prompt of n tokens
+    replays one a tick: its first token comes out of the n-th tick."""
+    cfg = engine_setup[0].scaled(local_window=8)
+    params = init_lm(jax.random.PRNGKey(0), cfg, ParallelCtx())
+    eng = _warm(cfg, params)
+    n = 5
+    assert _first_token_tick(eng, n) - eng.admit_step[7] == n - 1
+
+
+@pytest.mark.parametrize("window", [None, 8], ids=["chunked", "replay"])
+def test_prompt_tokens_counted_once(engine_setup, window):
+    """Over a drain, the ticks' ``prefill_tokens`` sum to the prompts'
+    lengths on the chunked path, and their ``replay_tokens`` on the replay
+    path; the other column reads 0."""
+    cfg, params = engine_setup
+    if window is not None:
+        cfg = cfg.scaled(local_window=window)
+        params = init_lm(jax.random.PRNGKey(0), cfg, ParallelCtx())
+    eng = ContinuousEngine(cfg, params, batch_slots=2, capacity=2 * C)
+    prompts = [list(range(1, 1 + n)) for n in (C + 9, 3, 1, 12)]
+    for uid, prompt in enumerate(prompts):
+        eng.submit(Request(uid=uid, prompt=prompt, max_new=2))
+    _drain(eng)
+    ticks = eng.record.snapshot()["ticks"]
+    total = sum(map(len, prompts))
+    taken, other = "prefill_tokens", "replay_tokens"
+    if window is not None:
+        taken, other = other, taken
+    assert sum(ticks[taken]) == total and sum(ticks[other]) == 0
+    assert len(ticks[taken]) == len(eng.record)
 
 
 def test_one_phase_sample_per_tick(engine_setup):
@@ -270,16 +311,21 @@ def _module_name(lowered):
 
 
 def test_engine_programs_have_stable_names(engine_setup):
-    """The decode step, the slot reset and the greedy pick lower to
-    fixed module names, on the single-device path and the runtime one."""
+    """The decode step, the mixed step, the slot reset and the greedy pick
+    lower to fixed module names, on the single-device path and the
+    runtime one."""
     from repro.launch.steps import build_continuous_serve
     from repro.serving.continuous import serve_greedy
 
     cfg, params = engine_setup
     eng = ContinuousEngine(cfg, params, batch_slots=2, capacity=16)
     cur = jnp.zeros(2, jnp.int32)
+    chunk = (jnp.zeros(eng.chunk, jnp.int32), np.int32(0), np.int32(0),
+             np.int32(1))
     assert _module_name(eng._step.lower(params, eng.caches, cur, cur)) \
         == "jit_serve_decode_step"
+    assert _module_name(eng._mixed.lower(params, eng.caches, cur, cur,
+                                         *chunk)) == "jit_serve_mixed_step"
     assert _module_name(eng._reset.lower(eng.caches, np.int32(0))) \
         == "jit_serve_reset_slot"
     assert _module_name(serve_greedy.lower(jnp.zeros((2, 8)))) \
@@ -291,6 +337,9 @@ def test_engine_programs_have_stable_names(engine_setup):
     caches = rt["init_caches"]()
     assert _module_name(rt["step"].lower(params, caches, cur, cur)) \
         == "jit_serve_decode_step"
+    assert _module_name(rt["mixed_step"].lower(params, caches, cur, cur,
+                                               *chunk)) \
+        == "jit_serve_mixed_step"
     assert _module_name(rt["reset"].lower(caches, np.int32(0))) \
         == "jit_serve_reset_slot"
 
